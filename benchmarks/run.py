@@ -94,6 +94,8 @@ def main(argv=None) -> int:
                     metavar="DIR",
                     help="also write BENCH_<name>.json per module to DIR")
     args = ap.parse_args(argv)
+    from repro.core import env
+    env.enable_compile_cache()
     meta = None
     if args.json is not None:
         os.makedirs(args.json, exist_ok=True)

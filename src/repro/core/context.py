@@ -29,6 +29,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core.policy import ReconfigPolicy
 from repro.core.telemetry import Telemetry, safe_ratio
@@ -128,6 +129,7 @@ class ContextSwitchEngine:
             "loads": 0, "load_seconds": 0.0, "bytes_loaded": 0,
             "switches": 0, "switch_seconds": 0.0, "evictions": 0,
             "hidden_load_seconds": 0.0, "context_changes": 0,
+            "prefetch_failures": 0,
         })
         # overlap accounting (all guarded by self._lock).  One loader
         # thread => at most one load window open at a time.
@@ -195,9 +197,9 @@ class ContextSwitchEngine:
                 return
         # slot already gone (e.g. explicit evict raced ahead) — fine.
 
-    def _submit_unlocked(self, desc: ContextDescriptor) -> Future:
-        fut = self._loader.submit(self._do_load, desc)
-        return fut
+    def _submit_unlocked(self, desc: ContextDescriptor,
+                         prefetch: bool = False) -> Future:
+        return self._loader.submit(self._do_load, desc, prefetch)
 
     def preload(self, name: str, block: bool = False,
                 allow_evict_active: bool = False) -> Future:
@@ -259,11 +261,20 @@ class ContextSwitchEngine:
                     known, active=self._active_name(), limit=limit):
                 for v in dec.evictions:
                     self._evict_name_unlocked(v)
-                fut = self._submit_unlocked(self._contexts[dec.net])
+                fut = self._submit_unlocked(self._contexts[dec.net],
+                                            prefetch=True)
                 self._pending[dec.net] = fut
                 futs.append(fut)
             self._kick_deferred_unlocked()   # evictions may free deferred
         return futs
+
+    def note_prefetch_failure(self):
+        """Count one failed prefetch (``ctx.prefetch_failures``).  A
+        prefetch is advisory — callers keep serving and the context pays
+        a demand load later — so the counter is the only trace a failed
+        shadow load (e.g. device memory exhausted) leaves."""
+        with self._lock:
+            self.stats["prefetch_failures"] += 1
 
     def _kick_deferred_unlocked(self):
         """Resubmit deferred loads whose slot just became available (FIFO:
@@ -302,7 +313,7 @@ class ContextSwitchEngine:
                 raise RuntimeError(f"no slot became loadable for {name!r}")
             time.sleep(0.001)
 
-    def _do_load(self, desc: ContextDescriptor):
+    def _do_load(self, desc: ContextDescriptor, prefetch: bool = False):
         slot = self._claim_slot(desc.name)
         t0 = self._clock()
         with self._lock:
@@ -315,6 +326,11 @@ class ContextSwitchEngine:
             # final barrier.
             if desc.shardings is not None:
                 bufs = jax.tree.map(jax.device_put, host, desc.shardings)
+            elif self.mesh is not None:
+                # replicated over the mesh once, here: weights left on
+                # one device would be re-sent to every device each step
+                bufs = jax.device_put(
+                    host, NamedSharding(self.mesh, PartitionSpec()))
             else:
                 bufs = jax.tree.map(jax.device_put, host)
             jax.block_until_ready(bufs)
@@ -336,6 +352,8 @@ class ContextSwitchEngine:
                 slot.ready_event.set()
                 self.policy.abort(desc.name)
                 self._load_started_at = None
+                if prefetch:     # counted before the future resolves
+                    self.stats["prefetch_failures"] += 1
                 self._kick_deferred_unlocked()
             if self._trace.enabled:
                 self._trace.instant(f"load-failed:{desc.name}",

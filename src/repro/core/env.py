@@ -21,15 +21,26 @@ Two kinds of knob live here:
 ``multi-device`` job fake a 4-device mesh on one CPU host:
 ``--xla_force_host_platform_device_count=N`` splits the host platform
 into N devices, enough for ``shard_map`` placement without hardware.
+
+``enable_compile_cache`` turns on JAX's persistent compilation cache for
+entry points (the serving launcher, ``benchmarks/run.py``,
+``chip_smoke.py``) so a second process on the same checkout skips the
+compile.  Tests never call it.
 """
 from __future__ import annotations
 
 import os
+from pathlib import Path
 from typing import Optional
 
-__all__ = ["backend_initialized", "describe", "enable_x64",
+__all__ = ["backend_initialized", "compile_cache_dir", "describe",
+           "enable_compile_cache", "enable_x64",
            "gpu_latency_hiding_flags", "set_host_device_count",
            "set_platform"]
+
+# <checkout>/.jax_cache: fixed, because the cache directory is part of
+# every entry's lookup — a per-run path would never hit
+_REPO_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 # flags vetted for serving-shaped GPU programs: overlap collective /
 # host-transfer latency behind compute instead of serializing on it
@@ -85,6 +96,26 @@ def set_host_device_count(n: Optional[int]) -> None:
     if n < 1:
         raise ValueError(f"host device count must be >= 1, got {n}")
     _add_xla_flags(f"--xla_force_host_platform_device_count={n}")
+
+
+def compile_cache_dir() -> tuple[str, bool]:
+    """Where the persistent compilation cache lives, and whether code has
+    to set it: ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it
+    (jax reads that variable itself), else ``<checkout>/.jax_cache``."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir, False
+    return str(_REPO_CACHE), True
+
+
+def enable_compile_cache() -> str:
+    """Turn on the persistent compilation cache before the first compile
+    (see ``compile_cache_dir``); returns the directory in use."""
+    path, set_in_code = compile_cache_dir()
+    if set_in_code:
+        import jax
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def gpu_latency_hiding_flags() -> None:

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.compat import shard_map
+from jax import shard_map
 
 
 def pipeline_apply(stage_fn: Callable, mesh: Mesh, params_stacked, x,

@@ -18,7 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.compat import pltpu
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BC = 128
 DEFAULT_BF = 128

@@ -36,7 +36,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.compat import pltpu
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -85,9 +85,11 @@ def _paged_decode_kernel_q(pos_ref, pt_ref, q_ref, k_ref, v_ref, ks_ref,
                            vs_ref, o_ref, m_scr, l_scr, acc_scr, *,
                            scale: float, page: int, np_row: int):
     """int8-bank variant: k/v tiles are int8 codes and two extra
-    (1, 1, page) scale tiles ride the SAME page-table index map, so the
-    per-position scale arrives with its page and the dequantize happens
-    in VMEM right before the matmul."""
+    (1, 1, 1, page) scale tiles ride the SAME page-table index map, so the
+    per-position scale arrives with its page as one lane-major row.  The
+    dequantize folds into the matmuls: ``q.(k*ks)^T == (q.k^T)*ks`` and
+    ``p.(v*vs) == (p*vs).v``, so the scales broadcast along lanes and no
+    (page, hd) dequantized tile is ever built."""
     b = pl.program_id(0)
     j = pl.program_id(2)
     pos = pos_ref[b]
@@ -103,10 +105,10 @@ def _paged_decode_kernel_q(pos_ref, pt_ref, q_ref, k_ref, v_ref, ks_ref,
     @pl.when(k_start <= pos)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32) * scale       # (G, hd)
-        k = (k_ref[0, 0].astype(jnp.float32)
-             * ks_ref[0, 0][:, None])                     # (page, hd)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (page, hd)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
+        s = s * ks_ref[0, 0]                              # (1, page)
         cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(cols <= pos, s, NEG_INF)
         m_prev, l_prev = m_scr[...], l_scr[...]
@@ -115,9 +117,9 @@ def _paged_decode_kernel_q(pos_ref, pt_ref, q_ref, k_ref, v_ref, ks_ref,
         p = jnp.exp(s - m_new)
         l_scr[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
         m_scr[...] = m_new
-        v = (v_ref[0, 0].astype(jnp.float32)
-             * vs_ref[0, 0][:, None])                     # (page, hd)
-        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+        v = v_ref[0, 0].astype(jnp.float32)                  # (page, hd)
+        pv = jax.lax.dot_general(p * vs_ref[0, 0], v,
+                                 (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         acc_scr[...] = acc_scr[...] * alpha + pv
 
@@ -134,8 +136,8 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, page_table, pos, *,
     """q: (B, Hkv, G, hd); k_pages/v_pages: (NP, Hkv, page, hd) shared
     pool; page_table: (B, P) int32 pool-page ids (dead entries must hold
     a valid index — the park page); pos: (B,) int32 valid length per
-    row.  ``k_scale``/``v_scale`` ((NP, Hkv, page) f32) select the int8
-    bank path: codes dequantize inside the kernel."""
+    row.  ``k_scale``/``v_scale`` ((NP, Hkv, 1, page) f32) select the
+    int8 bank path: codes dequantize inside the kernel."""
     B, Hkv, G, hd = q.shape
     NP, _, page, _ = k_pages.shape
     P = page_table.shape[1]
@@ -155,8 +157,11 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, page_table, pos, *,
     if quantized:
         kernel = functools.partial(_paged_decode_kernel_q, scale=scale,
                                    page=page, np_row=P)
+        # (1, page) trailing block: legal for the TPU tiling (a unit
+        # second-minor dim equal to the array's), unlike (1, 1, page)
+        # blocks over an (NP, Hkv, page) leaf
         scale_spec = pl.BlockSpec(
-            (1, 1, page), lambda b, h, j, pos, pt: (pt[b, j], h, 0))
+            (1, 1, 1, page), lambda b, h, j, pos, pt: (pt[b, j], h, 0, 0))
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
     else:
@@ -254,7 +259,8 @@ def _paged_verify_kernel_q(pos_ref, pt_ref, anc_ref, q_ref, k_ref, v_ref,
                            l_scr, acc_scr, *, scale: float, tree: bool,
                            page: int, np_row: int, K: int, G: int):
     """int8-bank verify: cache pages dequantize in VMEM via the
-    co-travelling (1, 1, page) scale tiles; the block's own K keys/values
+    co-travelling (1, 1, 1, page) scale tiles (folded into the matmuls as
+    in ``_paged_decode_kernel_q``); the block's own K keys/values
     stay full precision (they have not been written to the pool yet)."""
     b = pl.program_id(0)
     j = pl.program_id(2)
@@ -266,13 +272,15 @@ def _paged_verify_kernel_q(pos_ref, pt_ref, anc_ref, q_ref, k_ref, v_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def _fold(s, v):
+    def _fold(s, v, v_scale=None):
         m_prev, l_prev = m_scr[...], l_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
         l_scr[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
         m_scr[...] = m_new
+        if v_scale is not None:          # int8 page: (1, page) row scales
+            p = p * v_scale
         pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         acc_scr[...] = acc_scr[...] * alpha + pv
@@ -282,13 +290,13 @@ def _paged_verify_kernel_q(pos_ref, pt_ref, anc_ref, q_ref, k_ref, v_ref,
     @pl.when(k_start < pos)
     def _cache_page():
         q = q_ref[0, 0].astype(jnp.float32) * scale       # (K*G, hd)
-        k = (k_ref[0, 0].astype(jnp.float32)
-             * ks_ref[0, 0][:, None])                     # (page, hd)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (page, hd)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
+        s = s * ks_ref[0, 0]                              # (1, page)
         cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         _fold(jnp.where(cols < pos, s, NEG_INF),
-              v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None])
+              v_ref[0, 0].astype(jnp.float32), vs_ref[0, 0])
 
     @pl.when(j == np_row - 1)
     def _block_and_finalize():
@@ -320,7 +328,7 @@ def paged_verify_attention_kernel(q, k_pages, v_pages, kb, vb, page_table,
     k_pages/v_pages: (NP, Hkv, page, hd) shared pool BEFORE the block's
     writes; kb/vb: (B, Hkv, K, hd) block keys/values; page_table: (B, P)
     int32; pos: (B,) int32 base positions.  ``k_scale``/``v_scale``
-    ((NP, Hkv, page) f32) select the int8 bank path.  ``tree``
+    ((NP, Hkv, 1, page) f32) select the int8 bank path.  ``tree``
     ((B, K) int32 ancestor bitmasks) replaces the intra-block causal
     mask with per-row tree visibility (bit j of ``tree[b, i]`` = block
     token j visible to block query i)."""
@@ -359,7 +367,8 @@ def paged_verify_attention_kernel(q, k_pages, v_pages, kb, vb, page_table,
                                    tree=is_tree, page=page, np_row=P,
                                    K=K, G=G)
         scale_spec = pl.BlockSpec(
-            (1, 1, page), lambda b, h, j, pos, pt, anc: (pt[b, j], h, 0))
+            (1, 1, 1, page),
+            lambda b, h, j, pos, pt, anc: (pt[b, j], h, 0, 0))
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
     else:
@@ -460,10 +469,10 @@ def _paged_decode_partial_kernel_q(pos_ref, pt_ref, base_ref, q_ref,
     @pl.when(owned & (k_start <= pos))
     def _body():
         q = q_ref[0, 0].astype(jnp.float32) * scale       # (G, hd)
-        k = (k_ref[0, 0].astype(jnp.float32)
-             * ks_ref[0, 0][:, None])                     # (page, hd)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (page, hd)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
+        s = s * ks_ref[0, 0]                              # (1, page)
         cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(cols <= pos, s, NEG_INF)
         m_prev, l_prev = m_scr[...], l_scr[...]
@@ -472,9 +481,9 @@ def _paged_decode_partial_kernel_q(pos_ref, pt_ref, base_ref, q_ref,
         p = jnp.exp(s - m_new)
         l_scr[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
         m_scr[...] = m_new
-        v = (v_ref[0, 0].astype(jnp.float32)
-             * vs_ref[0, 0][:, None])                     # (page, hd)
-        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+        v = v_ref[0, 0].astype(jnp.float32)                  # (page, hd)
+        pv = jax.lax.dot_general(p * vs_ref[0, 0], v,
+                                 (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         acc_scr[...] = acc_scr[...] * alpha + pv
 
@@ -526,9 +535,9 @@ def paged_decode_partial_kernel(q, k_pages, v_pages, page_table, pos,
                                    scale=scale, page=page, np_row=P,
                                    num_local=L)
         scale_spec = pl.BlockSpec(
-            (1, 1, page),
+            (1, 1, 1, page),
             lambda b, h, j, pos, pt, base:
-                (jnp.clip(pt[b, j] - base[0], 0, L - 1), h, 0))
+                (jnp.clip(pt[b, j] - base[0], 0, L - 1), h, 0, 0))
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
     else:

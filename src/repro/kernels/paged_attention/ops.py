@@ -31,7 +31,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, pos, *,
     instead of a contiguous row.  Dead table entries (past a row's
     allocation) must hold a valid pool index — the engine points them at
     the park page; they are masked by ``pos`` regardless.  An int8 pool
-    passes its (NP, Hkv, page) f32 ``k_scale``/``v_scale`` leaves and
+    passes its (NP, Hkv, 1, page) f32 ``k_scale``/``v_scale`` leaves and
     the kernel dequantizes in VMEM."""
     if interpret is None:
         interpret = not _on_tpu()

@@ -34,13 +34,14 @@ def gather_pages(pages, page_table):
 
 
 def gather_scales(scales, page_table):
-    """(NP, Hkv, page) int8-bank scale leaf + (B, P) table ->
+    """(NP, Hkv, 1, page) int8-bank scale leaf + (B, P) table ->
     (B, Hkv, P*page) per-position scales — ``gather_pages`` minus the
     head-dim axis, so a gathered int8 row dequantizes elementwise as
-    ``codes * scales[..., None]``."""
-    g = scales[jnp.asarray(page_table, jnp.int32)]  # (B, P, Hkv, page)
-    B, P, Hkv, page = g.shape
-    return g.transpose(0, 2, 1, 3).reshape(B, Hkv, P * page)
+    ``codes * scales[..., None]``.  The unit axis is the kernel's tiling:
+    each page's scales are one lane-major (1, page) row."""
+    g = scales[jnp.asarray(page_table, jnp.int32)]  # (B, P, Hkv, 1, page)
+    B, P, Hkv, _, page = g.shape
+    return g[:, :, :, 0].transpose(0, 2, 1, 3).reshape(B, Hkv, P * page)
 
 
 def _dequant(pages, scales, page_table):
@@ -53,7 +54,7 @@ def paged_decode_reference(q, k_pages, v_pages, page_table, pos, *,
                            scale: float | None = None,
                            k_scale=None, v_scale=None):
     """q: (B, H, hd) -> (B, H, hd); see module docstring for layouts.
-    ``k_scale``/``v_scale`` ((NP, Hkv, page) f32) mark an int8 bank:
+    ``k_scale``/``v_scale`` ((NP, Hkv, 1, page) f32) mark an int8 bank:
     codes are dequantized after the gather, then the row oracle runs
     unchanged."""
     if k_scale is not None:

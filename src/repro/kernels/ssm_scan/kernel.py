@@ -20,7 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.compat import pltpu
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BD = 256     # channels per program
 DEFAULT_BL = 128     # time steps per tile
@@ -50,7 +50,7 @@ def _ssm_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, s0_ref,
         dBu = (dt_t * u_t).T * B_t                        # (bd, N)
         s = dA * s + dBu
         y_t = jnp.sum(s * C_t, axis=-1)[None] + u_t * Dg  # (1, bd)
-        pl.store(y_ref, (pl.ds(0, 1), pl.ds(t, 1), slice(None)), y_t[None])
+        y_ref[0, pl.ds(t, 1), :] = y_t
         return s
 
     s = jax.lax.fori_loop(0, bl, step, s_scr[...])

@@ -30,7 +30,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.compat import pltpu
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 DEFAULT_BK = 512

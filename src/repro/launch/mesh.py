@@ -9,14 +9,9 @@ import jax
 
 
 def make_mesh_auto(shape, axes):
-    """jax.make_mesh with Auto axis types where the API exists (the
-    ``axis_types`` kwarg and ``AxisType`` arrived after 0.4; older
-    releases are Auto-only, so omitting it is equivalent)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis typed Auto (GSPMD propagation)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

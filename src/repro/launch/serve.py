@@ -35,6 +35,10 @@ Four modes:
 
 Both route every slot/eviction/prefetch decision through the shared
 ``ReconfigPolicy`` — there is no scheduling logic in this file.
+
+``--widths published`` serves each arch at the widths it was published
+with (e.g. ``tinyllama-1.1b``: 22 layers, d_model 2048) instead of the
+smoke-test-sized default; weights are random, drawn from fixed seeds.
 """
 from __future__ import annotations
 
@@ -46,7 +50,7 @@ import time
 import jax
 import numpy as np
 
-from repro.configs import get_arch, reduced as make_reduced
+from repro.configs import get_arch, override, reduced as make_reduced
 from repro.models.model import build_model
 from repro.serve.scheduler import ContinuousScheduler, SwitchScheduler
 from repro.serve.switching import ServedModel, SwitchableServer
@@ -57,29 +61,39 @@ def build_server(names: list[str], slots: int, max_len: int,
                  temperature: float = 0.0,
                  load_delay_s: float = 0.0,
                  arch_overrides: dict | None = None,
-                 telemetry: Telemetry | None = None
+                 telemetry: Telemetry | None = None,
+                 reduced: bool = True,
+                 mesh=None,
                  ) -> tuple[SwitchableServer, dict]:
-    """Register reduced versions of `names` behind one SwitchableServer.
+    """Register `names` behind one SwitchableServer, with weights drawn
+    from ``jax.random.key(i)`` for the i-th name.
 
-    ``load_delay_s`` sleeps in each ``weights_fn`` to emulate streaming a
-    full-size context over the host->device link (benchmarks use it: the
-    reduced CPU test models are in-memory, real contexts are not).
-    ``arch_overrides`` are extra reduced-config fields (e.g. float32
-    dtypes for tests that compare two numerically different execution
-    paths bitwise)."""
+    ``reduced`` (default) serves the smoke-test-sized config of each
+    arch; ``reduced=False`` serves the widths the arch was published
+    with.  Weights live on the host after registration, so every context
+    load is a real host->device transfer (``ctx.bytes_loaded``).
+    ``load_delay_s`` sleeps in each ``weights_fn`` to emulate a slower
+    host->device link.  ``arch_overrides`` are extra config fields (e.g.
+    float32 dtypes for tests that compare two numerically different
+    execution paths bitwise).  ``mesh`` makes every load replicate the
+    weights over that mesh once (for engines whose page bank is laid
+    out over it)."""
     import jax.numpy as jnp
-    server = SwitchableServer(num_slots=slots, telemetry=telemetry)
+    server = SwitchableServer(num_slots=slots, mesh=mesh,
+                              telemetry=telemetry)
     cfgs = {}
     over = arch_overrides or {}
     for i, name in enumerate(names):
-        cfg = make_reduced(get_arch(name), **over)
+        cfg = get_arch(name)
+        cfg = (make_reduced(cfg, **over) if reduced
+               else override(cfg, **over))
         cfgs[name] = cfg
         model = build_model(cfg, cache_dtype=jnp.float32
                             if over.get("dtype") == "float32"
                             else jnp.bfloat16)
-        params = model.init(jax.random.key(i))
+        host = jax.device_get(model.init(jax.random.key(i)))
 
-        def weights_fn(p=params):
+        def weights_fn(p=host):
             if load_delay_s:
                 time.sleep(load_delay_s)
             return p
@@ -97,6 +111,52 @@ def request_stream(names, cfgs, n_requests, batch, seq, seed):
         name = names[r % len(names)]
         toks = rng.integers(0, cfgs[name].vocab_size, (batch, seq))
         yield name, toks
+
+
+def shard_mesh(shards: int | None):
+    """The device mesh a ``--shards N`` page bank is laid out over, or
+    None when fewer than N devices are visible (the bank is then sharded
+    only logically: N free-lists on one device)."""
+    if shards is None or shards <= 1 or jax.device_count() < shards:
+        return None
+    from repro.distributed.mesh import make_mesh
+    return make_mesh((shards,), ("model",))
+
+
+def bank_placement(shards: int | None, mesh) -> str:
+    """How the paged bank is laid out: ``single`` (one device, one
+    free-list), ``logical`` (``shards`` free-lists on one device) or
+    ``mesh`` (the page axis split over the devices of ``mesh``)."""
+    if mesh is not None:
+        return "mesh"
+    return "logical" if shards is not None and shards > 1 else "single"
+
+
+def serve_report(server: SwitchableServer, mode: str, wall: float,
+                 n_requests: int, extra: dict) -> dict:
+    """The launcher's JSON report: context-engine counters plus the
+    scheduler's ``extra`` fields and the environment the run used."""
+    from repro.core import env
+    stats = server.engine.stats
+    return {
+        "mode": mode,
+        "wall_s": round(wall, 3),
+        "requests_per_s": round(n_requests / wall, 2) if wall else 0.0,
+        "switches": stats["switches"],
+        "context_changes": stats["context_changes"],
+        "mean_switch_us": round(1e6 * stats["switch_seconds"]
+                                / max(stats["switches"], 1), 1),
+        "loads": stats["loads"],
+        "mean_load_ms": round(1e3 * stats["load_seconds"]
+                              / max(stats["loads"], 1), 2),
+        "bytes_loaded": stats["bytes_loaded"],
+        "prefetch_failures": stats["prefetch_failures"],
+        "hidden_load_fraction": round(
+            server.engine.hidden_load_fraction(), 3),
+        **extra,
+        "env": env.describe(),
+        "log_tail": server.log[-3:],
+    }
 
 
 def main(argv=None) -> int:
@@ -159,7 +219,12 @@ def main(argv=None) -> int:
                          "the cached pages, cold admissions to the "
                          "least-loaded shard).  When at least this many "
                          "devices are visible the bank is also placed "
-                         "over a device mesh")
+                         "over a device mesh (the report's "
+                         "bank_placement says which happened)")
+    ap.add_argument("--widths", choices=("reduced", "published"),
+                    default="reduced",
+                    help="serve each arch at smoke-test widths (default) "
+                         "or at the widths it was published with")
     ap.add_argument("--platform", default=None,
                     choices=("cpu", "gpu", "tpu"),
                     help="pin jax to one platform (default: jax's own "
@@ -207,6 +272,7 @@ def main(argv=None) -> int:
     if args.x64:
         env.enable_x64(True)
     env.set_host_device_count(args.host_devices)
+    env.enable_compile_cache()
     if args.quantize_kv != "none" and not args.paged \
             and args.mode != "speculative":
         ap.error("--quantize-kv targets the shared page bank: it "
@@ -250,8 +316,11 @@ def main(argv=None) -> int:
         ps = min(args.page_size, max_len)
         max_len = -(-max_len // ps) * ps
     telemetry = Telemetry(trace=args.trace_out is not None)
+    mesh = shard_mesh(args.shards)
     server, cfgs = build_server(names, args.slots, max_len,
-                                telemetry=telemetry)
+                                telemetry=telemetry,
+                                reduced=args.widths == "reduced",
+                                mesh=mesh)
     stats_stop = None
     if args.stats_interval > 0:
         import threading
@@ -276,14 +345,6 @@ def main(argv=None) -> int:
         reqs = list(request_stream(names, cfgs, args.requests,
                                    args.batch, args.seq, args.seed))
 
-    mesh = None
-    if args.shards is not None and args.shards > 1 \
-            and jax.device_count() >= args.shards:
-        # enough devices: place the sharded bank over a real mesh (the
-        # host allocator shards regardless; this adds device placement)
-        from repro.distributed.mesh import make_mesh
-        mesh = make_mesh((args.shards,), ("model",))
-
     t0 = time.perf_counter()
     if args.mode in ("queue", "continuous", "speculative"):
         sched_cls = (SwitchScheduler if args.mode == "queue" else
@@ -306,6 +367,8 @@ def main(argv=None) -> int:
                 f.result()
                 lat.append(time.perf_counter() - t_in)
         extra = {**sched.snapshot()}
+        if args.paged:
+            extra["bank_placement"] = bank_placement(args.shards, mesh)
         if lat:
             extra["latency_p50_s"] = round(float(np.percentile(lat, 50)), 4)
             extra["latency_p99_s"] = round(float(np.percentile(lat, 99)), 4)
@@ -318,25 +381,7 @@ def main(argv=None) -> int:
         extra = {}
     wall = time.perf_counter() - t0
 
-    stats = server.engine.stats
-    report = {
-        "mode": args.mode,
-        "wall_s": round(wall, 3),
-        "requests_per_s": round(args.requests / wall, 2) if wall else 0.0,
-        "switches": stats["switches"],
-        "context_changes": stats["context_changes"],
-        "mean_switch_us": round(1e6 * stats["switch_seconds"]
-                                / max(stats["switches"], 1), 1),
-        "loads": stats["loads"],
-        "mean_load_ms": round(1e3 * stats["load_seconds"]
-                              / max(stats["loads"], 1), 2),
-        "bytes_loaded": stats["bytes_loaded"],
-        "hidden_load_fraction": round(
-            server.engine.hidden_load_fraction(), 3),
-        **extra,
-        "env": env.describe(),
-        "log_tail": server.log[-3:],
-    }
+    report = serve_report(server, args.mode, wall, args.requests, extra)
     if stats_stop is not None:
         stats_stop.set()
     if args.trace_out:

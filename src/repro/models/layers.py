@@ -10,7 +10,8 @@ three entry points matching the framework's execution modes:
 """
 from __future__ import annotations
 
-from typing import Any, NamedTuple
+import math
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -80,16 +81,22 @@ class KVCache(NamedTuple):
 
 
 def attn_specs(cfg: ArchConfig) -> dict:
+    # explicit 1/sqrt(fan_in): the default reads fan-in off the second-
+    # to-last axis, which for these head-split weights is heads/head_dim
+    # (~8x too large at d_model 2048 — near-argmax attention whose bf16
+    # rounding flips decorrelate the logits from an f32 reference)
     d = cfg.d_model
+    s_in = 1.0 / math.sqrt(d)
+    s_out = 1.0 / math.sqrt(cfg.num_heads * cfg.head_dim)
     return {
         "wq": PSpec((d, cfg.num_heads, cfg.head_dim),
-                    ("embed", "heads", "head_dim")),
+                    ("embed", "heads", "head_dim"), scale=s_in),
         "wk": PSpec((d, cfg.num_kv_heads, cfg.head_dim),
-                    ("embed", "kv_heads", "head_dim")),
+                    ("embed", "kv_heads", "head_dim"), scale=s_in),
         "wv": PSpec((d, cfg.num_kv_heads, cfg.head_dim),
-                    ("embed", "kv_heads", "head_dim")),
+                    ("embed", "kv_heads", "head_dim"), scale=s_in),
         "wo": PSpec((cfg.num_heads, cfg.head_dim, d),
-                    ("heads", "head_dim", "embed")),
+                    ("heads", "head_dim", "embed"), scale=s_out),
     }
 
 
@@ -179,15 +186,21 @@ def causal_mask(S: int, window: int = 0) -> jax.Array:
 
 
 def _sdpa_auto(q, k, v, cfg: ArchConfig, unroll: bool = False,
-               mesh=None, rules=None):
+               mesh=None, rules=None, shard=None):
+    """``shard`` (a ``BankShard``): the program runs beside a page bank
+    split over ``shard.mesh``, so the flash kernel runs replicated."""
     import repro.kernels as kernels
     S = q.shape[1]
     if kernels.use_kernels() and S == k.shape[1]:
         from repro.kernels.flash_attention.ops import flash_attention
         interp = None if kernels.get_mode() == "auto" else True
-        out = flash_attention(q.swapaxes(1, 2), k.swapaxes(1, 2),
-                              v.swapaxes(1, 2), causal=True,
-                              window=cfg.sliding_window, interpret=interp)
+
+        def kernel(q, k, v):
+            return flash_attention(q, k, v, causal=True,
+                                   window=cfg.sliding_window,
+                                   interpret=interp)
+        out = _replicated(kernel, shard)(q.swapaxes(1, 2), k.swapaxes(1, 2),
+                                         v.swapaxes(1, 2))
         return out.swapaxes(1, 2)
     if S >= ATTN_CHUNK_THRESHOLD:
         return _sdpa_chunked(q, k, v, cfg, unroll=unroll, mesh=mesh,
@@ -206,14 +219,15 @@ def attention(params, x, positions, cfg: ArchConfig, unroll: bool = False,
 
 def attention_prefill(params, x, positions, cfg: ArchConfig, max_len: int,
                       cache_dtype=jnp.bfloat16, unroll: bool = False,
-                      mesh=None, rules=None):
+                      mesh=None, rules=None, shard=None):
     """Prefill from position 0: returns output and a fixed-size cache.
 
     Full attention: cache length == max_len.  Sliding window: cache length ==
-    window, laid out as a ring (slot = position % window).
+    window, laid out as a ring (slot = position % window).  ``shard``: see
+    ``_sdpa_auto``.
     """
     q, k, v = _qkv(params, x, positions, cfg)
-    out = _sdpa_auto(q, k, v, cfg, unroll, mesh, rules)
+    out = _sdpa_auto(q, k, v, cfg, unroll, mesh, rules, shard)
     out = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
 
     S, W = x.shape[1], cfg.sliding_window
@@ -382,16 +396,18 @@ class PagedKV(NamedTuple):
     """Shared page pool: virtual row position j*page+s of a request lives
     at ``pool[table[j], :, s]`` for that request's page table.
 
-    ``ks``/``vs`` are the int8 bank's scale leaves ((NP, Hkv, page) f32,
-    ``None`` for full-precision pools): when present, ``k``/``v`` hold
+    ``ks``/``vs`` are the int8 bank's scale leaves ((NP, Hkv, 1, page)
+    f32, ``None`` for full-precision pools): when present, ``k``/``v`` hold
     symmetric-absmax int8 codes and the real value of pool entry
-    ``[p, h, s, :]`` is ``k[p, h, s, :] * ks[p, h, s]`` — one scale per
+    ``[p, h, s, :]`` is ``k[p, h, s, :] * ks[p, h, 0, s]`` — one scale per
     token per kv head, riding the same page table as the codes, so a
     single decoded token quantizes independently without rescaling its
-    page."""
+    page.  The unit axis is the paged kernels' tiling: a page's scales
+    are one (1, page) lane-major row, a block the TPU lowering accepts
+    (a (1, page) block of an (NP, Hkv, page) leaf is not)."""
     k: jax.Array          # (NP, Hkv, page, hd) — cache dtype, or int8
     v: jax.Array
-    ks: Any = None        # (NP, Hkv, page) f32 scales (int8 pools only)
+    ks: Any = None        # (NP, Hkv, 1, page) f32 scales (int8 pools only)
     vs: Any = None
 
 
@@ -408,7 +424,7 @@ def init_page_pool(cfg: ArchConfig, num_pages: int, page: int,
                    quantized: bool = False) -> PagedKV:
     shape = (num_pages, cfg.num_kv_heads, page, cfg.head_dim)
     if quantized:
-        sshape = shape[:-1]
+        sshape = (num_pages, cfg.num_kv_heads, 1, page)
         if abstract:
             return PagedKV(k=jax.ShapeDtypeStruct(shape, jnp.int8),
                            v=jax.ShapeDtypeStruct(shape, jnp.int8),
@@ -475,8 +491,8 @@ def _page_write(cache: PagedKV, k, v, tables, positions, wmask=None):
         vq, vsc = quantize_kv(v)
         return PagedKV(k=cache.k.at[pids, :, slots, :].set(kq),
                        v=cache.v.at[pids, :, slots, :].set(vq),
-                       ks=cache.ks.at[pids, :, slots].set(ksc),
-                       vs=cache.vs.at[pids, :, slots].set(vsc))
+                       ks=cache.ks.at[pids, :, 0, slots].set(ksc),
+                       vs=cache.vs.at[pids, :, 0, slots].set(vsc))
     k_new = cache.k.at[pids, :, slots, :].set(k.astype(cache.k.dtype))
     v_new = cache.v.at[pids, :, slots, :].set(v.astype(cache.v.dtype))
     return PagedKV(k=k_new, v=v_new)
@@ -494,6 +510,30 @@ def _gather_dequant(cache: PagedKV, tables, dtype):
     return kg, vg
 
 
+class BankShard(NamedTuple):
+    """A page bank split over mesh axis ``axis`` (page axis).  With
+    ``local_read`` attention is shard_mapped so each shard reads only its
+    own slice (``attention_*_pages_sharded``); otherwise every device
+    reads the whole bank (the global-gather path)."""
+    mesh: Any
+    axis: str
+    local_read: bool = True
+
+
+def _replicated(kernel, shard: Optional[BankShard]):
+    """Run a Pallas call in a program laid out over ``shard.mesh`` (the
+    global-gather paged path, and prefill beside a split bank).  Mosaic
+    kernels cannot be auto-partitioned, so the call is shard_mapped with
+    every operand replicated: each device gathers whole operands (the
+    whole bank) and runs the one-device kernel, bitwise its result."""
+    if shard is None:
+        return kernel
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as Ps
+    return shard_map(kernel, mesh=shard.mesh, in_specs=Ps(),
+                     out_specs=Ps(), check_vma=False)
+
+
 def attention_decode_pages(params, x, pos, cache: PagedKV, tables,
                            cfg: ArchConfig, wmask=None, shard=None):
     """One-step decode against the shared page pool.  x: (B, 1, D);
@@ -506,9 +546,10 @@ def attention_decode_pages(params, x, pos, cache: PagedKV, tables,
     pages under the same ``idx <= pos`` mask, so live rows' outputs are
     bitwise the row engine's.
 
-    ``shard`` (``(mesh, axis)``, optional) switches to the shard_mapped
-    local-read path: see ``attention_decode_pages_sharded``."""
-    if shard is not None:
+    ``shard`` (a ``BankShard``, optional) says the bank is split over a
+    mesh; with ``local_read`` it switches to the shard_mapped local-read
+    path: see ``attention_decode_pages_sharded``."""
+    if shard is not None and shard.local_read:
         return attention_decode_pages_sharded(params, x, pos, cache,
                                               tables, cfg, shard,
                                               wmask=wmask)
@@ -523,10 +564,12 @@ def attention_decode_pages(params, x, pos, cache: PagedKV, tables,
     if kernels.use_kernels():
         from repro.kernels.paged_attention.ops import paged_decode_attention
         interp = None if kernels.get_mode() == "auto" else True
-        out = paged_decode_attention(q[:, 0], cache.k, cache.v, tables,
-                                     pos, k_scale=cache.ks,
-                                     v_scale=cache.vs,
-                                     interpret=interp)[:, None]
+
+        def kernel(q, k, v, tables, pos, ks, vs):
+            return paged_decode_attention(q, k, v, tables, pos, k_scale=ks,
+                                          v_scale=vs, interpret=interp)
+        out = _replicated(kernel, shard)(q[:, 0], cache.k, cache.v, tables,
+                                         pos, cache.ks, cache.vs)[:, None]
     elif cache.ks is not None:
         kg, vg = _gather_dequant(cache, tables, x.dtype)
         valid = jnp.arange(kg.shape[2])[None, :] <= pos[:, None]
@@ -564,9 +607,10 @@ def attention_verify_pages(params, x, pos, cache: PagedKV, tables,
     writer per depth through ``wmask`` (the scatter has one slot per
     position).
 
-    ``shard`` (``(mesh, axis)``, optional) switches to the shard_mapped
-    local-read path: see ``attention_verify_pages_sharded``."""
-    if shard is not None:
+    ``shard`` (a ``BankShard``, optional) says the bank is split over a
+    mesh; with ``local_read`` it switches to the shard_mapped local-read
+    path: see ``attention_verify_pages_sharded``."""
+    if shard is not None and shard.local_read:
         return attention_verify_pages_sharded(params, x, pos, cache,
                                               tables, cfg, shard,
                                               wmask=wmask, offsets=offsets,
@@ -582,10 +626,13 @@ def attention_verify_pages(params, x, pos, cache: PagedKV, tables,
     if kernels.use_kernels():
         from repro.kernels.paged_attention.ops import paged_verify_attention
         interp = None if kernels.get_mode() == "auto" else True
-        out = paged_verify_attention(q, cache.k, cache.v, k, v, tables,
-                                     pos, k_scale=cache.ks,
-                                     v_scale=cache.vs, tree=tree,
-                                     interpret=interp)
+
+        def kernel(q, kp, vp, k, v, tables, pos, ks, vs, tree):
+            return paged_verify_attention(q, kp, vp, k, v, tables, pos,
+                                          k_scale=ks, v_scale=vs, tree=tree,
+                                          interpret=interp)
+        out = _replicated(kernel, shard)(q, cache.k, cache.v, k, v, tables,
+                                         pos, cache.ks, cache.vs, tree)
     elif cache.ks is not None:
         from repro.kernels.verify_attention.ref import verify_reference
         kg, vg = _gather_dequant(cache, tables, x.dtype)
@@ -703,14 +750,14 @@ def _heads_out(out, dt):
 def attention_decode_pages_sharded(params, x, pos, cache: PagedKV, tables,
                                    cfg: ArchConfig, shard, wmask=None):
     """``attention_decode_pages`` with the bank sharded over mesh axis
-    ``shard = (mesh, axis)``: each shard writes/reads only its local
+    ``shard.axis`` of ``shard.mesh``: each shard writes/reads only its local
     slice (local Pallas partial kernel when kernels are on, jnp partial
     otherwise) and the per-shard flash partials merge with one
     pmax/psum.  Allclose — not bitwise — to the global-gather path (the
     merge changes the softmax reduction order)."""
-    mesh, axis = shard
+    mesh, axis = shard.mesh, shard.axis
     from jax.sharding import PartitionSpec as Ps
-    from repro.distributed.compat import shard_map
+    from jax import shard_map
 
     B = x.shape[0]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
@@ -782,9 +829,9 @@ def attention_verify_pages_sharded(params, x, pos, cache: PagedKV, tables,
     pmax/psum; the block's own K keys/values are replicated, so their
     fold — and the intra-block causal/tree mask — happens once outside
     the shard_map.  Allclose, not bitwise, to the global-gather path."""
-    mesh, axis = shard
+    mesh, axis = shard.mesh, shard.axis
     from jax.sharding import PartitionSpec as Ps
-    from repro.distributed.compat import shard_map
+    from jax import shard_map
 
     B, K, _ = x.shape
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
@@ -861,8 +908,8 @@ def insert_pages(cache: PagedKV, rows: KVCache, tables) -> PagedKV:
         vq, vsc = quantize_kv(paged_view(rows.v))
         return PagedKV(k=cache.k.at[tables].set(kq),
                        v=cache.v.at[tables].set(vq),
-                       ks=cache.ks.at[tables].set(ksc),
-                       vs=cache.vs.at[tables].set(vsc))
+                       ks=cache.ks.at[tables].set(ksc[..., None, :]),
+                       vs=cache.vs.at[tables].set(vsc[..., None, :]))
 
     def scatter(pool, r):
         return pool.at[tables].set(paged_view(r).astype(pool.dtype))

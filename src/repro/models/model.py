@@ -191,9 +191,10 @@ class LM:
         park).  ``offsets``/``tree`` (paged verify only) select tree
         verification — per-node depth offsets and per-row ancestor
         bitmasks; see ``layers.attention_verify_pages``.  ``shard``
-        (``(mesh, axis)``, paged modes only) shard_maps the paged
-        attention so each mesh shard reads only its local slice of the
-        page bank (see ``layers.attention_decode_pages_sharded``).
+        (a ``layers.BankShard``, paged modes only) says the page bank is
+        split over a mesh: with ``local_read`` the paged attention is
+        shard_mapped so each mesh shard reads only its local slice (see
+        ``layers.attention_decode_pages_sharded``).
         """
         cfg = self.cfg
         mixer, ffn = typ
@@ -228,7 +229,7 @@ class LM:
                 a, nc = layers.attention_prefill(
                     p["attn"], h, positions, cfg, max_len,
                     self.cache_dtype, self.scan_unroll, self.mesh,
-                    self.rules)
+                    self.rules, shard)
             elif mode == "verify":
                 a, nc = layers.attention_verify(p["attn"], h, pos, cache,
                                                 cfg, wmask=wmask)
@@ -338,14 +339,19 @@ class LM:
                            cfg.norm_eps)
         return x, aux
 
-    def prefill(self, params, tokens, max_len: int, patch_embeds=None):
-        """Populate the decode cache.  Returns (last-pos logits, caches)."""
+    def prefill(self, params, tokens, max_len: int, patch_embeds=None,
+                shard=None):
+        """Populate the decode cache.  Returns (last-pos logits, caches).
+        ``shard`` (a ``layers.BankShard``): the caller's program also holds
+        a page bank split over a mesh (paged admission); the attention
+        kernel then runs replicated over that mesh."""
         cfg = self.cfg
         x = self._embed_in(params, tokens, patch_embeds)
         B, S = x.shape[:2]
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         x, aux, caches = self._run_blocks(params, x, positions, "prefill",
-                                          None, None, max_len=max_len)
+                                          None, None, max_len=max_len,
+                                          shard=shard)
         x = layers.rmsnorm(x, params["final_norm"].astype(x.dtype),
                            cfg.norm_eps)
         logits = self._head(params, x[:, -1:])
@@ -446,7 +452,7 @@ class LM:
         (see ``layers._page_write``); the page table is shared across
         layers — page id p is position range [j*page, (j+1)*page) of its
         owning row in EVERY layer's bank.  ``quantized`` stores the bank
-        as int8 codes plus (R, NP, Hkv, page) f32 scale leaves — roughly
+        as int8 codes plus (R, NP, Hkv, 1, page) f32 scale leaves — roughly
         half the bytes per page, so ~2x pages per HBM budget."""
         self._require_paged_support()
         out = {}
@@ -474,7 +480,7 @@ class LM:
         rules = self.rules if self.rules is not None else DEFAULT_RULES
         rules = rules.with_(kv_pages=axis)
         kv = ("layers", "kv_pages", "kv_heads", None, "head_dim")
-        sc = ("layers", "kv_pages", "kv_heads", None)
+        sc = ("layers", "kv_pages", "kv_heads", None, None)
 
         def one(bank):
             return layers.PagedKV(
@@ -516,8 +522,8 @@ class LM:
         ``live`` ((B,) bool, optional) routes non-live rows' cache writes
         to the park page — a retired slot's per-step garbage write must
         not land in pages already recycled to a neighbor.  ``shard``
-        (``(mesh, axis)``) switches attention to per-shard local bank
-        reads; see ``_apply_block``.  Returns (logits (B, 1, V), new
+        (a ``layers.BankShard``) marks a bank split over a mesh; see
+        ``_apply_block``.  Returns (logits (B, 1, V), new
         caches)."""
         cfg = self.cfg
         tables = jnp.asarray(tables, jnp.int32)

@@ -25,7 +25,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.distributed.compat import shard_map
+from jax import shard_map
 
 from repro.configs.base import ArchConfig, MoEConfig
 from repro.models.common import PSpec

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models import layers
 from repro.models.model import LM
 from repro.serve.pool import (Generation, PagePool, PrefixIndex, SharedBank,
                               ShardedPagePool, SlotPool)
@@ -432,9 +433,11 @@ class StepEngine(SlotPool):
                                        namespace=quantize_kv or "fp16")
 
         B, T, V = batch_size, temperature, model.cfg.vocab_size
-        # local_read: the paged programs shard_map attention so each mesh
-        # shard reads only its local bank slice (None == global gather)
-        shard_arg = (mesh, shard_axis) if self.local_read else None
+        # a bank split over the mesh: local_read shard_maps attention so
+        # each shard reads only its local slice; otherwise every device
+        # reads the whole bank (global gather)
+        shard_arg = (layers.BankShard(mesh, shard_axis, self.local_read)
+                     if mesh is not None else None)
 
         def _row_gumbel(rkeys, produced_at):
             """Per-slot gumbel fields for seeded rows: each slot's key is
@@ -549,7 +552,8 @@ class StepEngine(SlotPool):
             cache layout, which is what makes paged and row streams
             token-identical."""
             S = tokens.shape[1]
-            logits, rows = model.prefill(params, tokens, max_len)
+            logits, rows = model.prefill(params, tokens, max_len,
+                                         shard=shard_arg)
             last = logits[:, -1]                               # (b, V) f32
             if T > 0.0:
                 salted = jax.random.fold_in(state.key,

@@ -58,6 +58,17 @@ _LATENCY_HISTS = ("ttft_s", "queue_wait_s", "token_latency_s",
                   "gen_latency_s", "request_latency_s")
 
 
+def _advisory_prefetch(engine, names: list[str]):
+    """Stream the next context into the shadow slot.  Prefetch is
+    advisory: a failure must not take the caller down (the context pays
+    a demand load later), but it is counted — ``ctx.prefetch_failures``
+    also counts loads that fail later on the loader thread."""
+    try:
+        engine.prefetch(names, limit=1)
+    except Exception:
+        engine.note_prefetch_failure()
+
+
 @dataclass
 class _Request:
     name: str
@@ -243,12 +254,7 @@ class SwitchScheduler:
             self._note_load_cost(name, self._clock() - t0)
         # the paper's dynamic reconfiguration: next context streams into
         # the shadow slot while this streak executes (policy picks victims).
-        # Prefetch is advisory: a failure must not take the streak down
-        # (the next streak pays a demand load instead).
-        try:
-            engine.prefetch(upcoming, limit=1)
-        except Exception:
-            pass
+        _advisory_prefetch(engine, upcoming)
         for group in self._stack(streak):
             try:
                 out = self._run_group(name, group)
@@ -610,10 +616,7 @@ class ContinuousScheduler:
                 want, other = (_t, _d) if which == "target" else (_d, _t)
                 cse.preload(want)
                 cse.switch(want, wait=True)
-                try:
-                    cse.prefetch([other], limit=1)
-                except Exception:
-                    pass
+                _advisory_prefetch(cse, [other])
                 return cse.run_step(fn, *args)
 
             eng.runner = runner
@@ -744,13 +747,9 @@ class ContinuousScheduler:
                 self._tick_ctx = cur
             elif cand_p > self.switch_margin * max(cur_p, 1e-9):
                 # drain decision: stop stacking; stream the winner into
-                # the shadow slot behind the remaining steps (advisory —
-                # a failed prefetch just means a demand load later)
+                # the shadow slot behind the remaining steps
                 stack = False
-                try:
-                    self.server.engine.prefetch([cand], limit=1)
-                except Exception:
-                    pass
+                _advisory_prefetch(self.server.engine, [cand])
                 drained = eng.live_slots() == 0
                 preempt = cand_p > self.preempt_margin * max(cur_p, 1e-9)
                 if drained or (preempt and policy.is_resident(cand)):

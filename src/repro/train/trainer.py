@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import RunConfig
-from repro.distributed.compat import shard_map
+from jax import shard_map
 from repro.distributed.sharding import (
     DEFAULT_RULES, ShardingRules, shard_params_tree)
 from repro.models.model import LM

@@ -20,7 +20,7 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro.configs import get_arch, override, reduced  # noqa: E402
 from repro.configs.base import OptimizerConfig, ParallelConfig, RunConfig  # noqa: E402
-from repro.distributed.compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from repro.distributed.mesh import make_mesh  # noqa: E402
 from repro.distributed.sharding import DEFAULT_RULES, shard_params_tree  # noqa: E402
 from repro.models.model import build_model  # noqa: E402

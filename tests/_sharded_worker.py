@@ -121,6 +121,31 @@ def main():
     got = _run_stream(eng, p, prompts, 5, [None, None])
     record("local_read_chunked_streams", got == refs[(0.0, 8)])
 
+    # 5. Pallas kernels over a split bank (interpret mode here): a Mosaic
+    # kernel cannot be auto-partitioned, so the global-gather path runs it
+    # on the whole (all-gathered) bank and the local-read path runs the
+    # per-shard partial kernel — both stay greedy-identical to the
+    # one-device kernel engine, admitting in chunks or one shot (whose
+    # prefill runs the flash kernel replicated over the mesh)
+    import repro.kernels as kernels
+    kernels.set_mode("interpret")
+    try:
+        ok, runs = True, {}
+        for chunk in (8, None):
+            kw = dict(batch_size=2, max_len=256, paged=True, page_size=64,
+                      prefill_chunk=chunk)
+            ref_k = _run_stream(StepEngine(m, **kw), p, prompts, 5,
+                                [None, None])
+            got_g = _run_stream(StepEngine(m, mesh=mesh, **kw), p, prompts,
+                                5, [None, None])
+            got_l = _run_stream(StepEngine(m, mesh=mesh, local_read=True,
+                                           **kw), p, prompts, 5, [None, None])
+            ok &= got_g == ref_k and got_l == ref_k
+            runs[str(chunk)] = dict(gather=got_g, local=got_l, want=ref_k)
+    finally:
+        kernels.set_mode("auto")
+    record("mesh_kernel_streams", ok, **runs)
+
     print("RESULTS_JSON:" + json.dumps(RESULTS))
 
 

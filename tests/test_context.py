@@ -225,3 +225,44 @@ def test_delta_load_requires_base_resident():
     with pytest.raises(Exception):
         fut.result(timeout=10)
     eng.shutdown()
+
+
+def test_failed_prefetch_is_counted_not_swallowed():
+    """A weights_fn that raises on the loader thread during a prefetch
+    counts into ``ctx.prefetch_failures`` before its future resolves;
+    demand loads that fail are the caller's error, not a prefetch
+    failure."""
+    def boom():
+        raise MemoryError("device memory exhausted")
+
+    eng = ContextSwitchEngine(num_slots=2)
+    eng.register(_desc("a", 1.0))
+    eng.register(ContextDescriptor(name="bad", apply_fn=lambda p, x: x,
+                                   weights_fn=boom))
+    eng.preload("a", block=True)
+    eng.switch("a")
+    futs = eng.prefetch(["bad"], limit=1)
+    assert len(futs) == 1
+    with pytest.raises(MemoryError):
+        futs[0].result(timeout=10)
+    assert eng.stats["prefetch_failures"] == 1
+    assert eng.resident() == ["a"]            # the failed slot freed
+    with pytest.raises(MemoryError):
+        eng.preload("bad").result(timeout=10)
+    assert eng.stats["prefetch_failures"] == 1
+    eng.shutdown()
+
+
+def test_scheduler_counts_prefetch_that_raises():
+    """The schedulers' advisory prefetch keeps serving when the call
+    itself raises, and counts it."""
+    from repro.serve.scheduler import _advisory_prefetch
+    eng = ContextSwitchEngine(num_slots=2)
+
+    def raising_prefetch(names, limit=None):
+        raise RuntimeError("policy refused")
+
+    eng.prefetch = raising_prefetch
+    _advisory_prefetch(eng, ["x"])
+    assert eng.stats["prefetch_failures"] == 1
+    eng.shutdown()

@@ -56,7 +56,7 @@ def test_quantized_pool_layout(f32_lm):
         assert (NP, Hkv, page, hd) == (8, cfg.num_kv_heads, 16,
                                        cfg.head_dim)
         assert c.k.dtype == c.v.dtype == jnp.int8
-        assert c.ks.shape == c.vs.shape == (R, NP, Hkv, page)
+        assert c.ks.shape == c.vs.shape == (R, NP, Hkv, 1, page)
         assert c.ks.dtype == c.vs.dtype == jnp.float32
         # the headline ratio: codes+scales vs a bf16 pool, per token-head
         bf16 = 2 * hd
@@ -81,15 +81,15 @@ def _quantized_pool_from_rows(k, v, page, seed, spare_pages=3):
     vq, vsc = quantize_kv(v)
     kp = rng.integers(-127, 128, (NP, Hkv, page, hd)).astype(np.int8)
     vp = rng.integers(-127, 128, (NP, Hkv, page, hd)).astype(np.int8)
-    ks = rng.random((NP, Hkv, page)).astype(np.float32)
-    vs = rng.random((NP, Hkv, page)).astype(np.float32)
+    ks = rng.random((NP, Hkv, 1, page)).astype(np.float32)
+    vs = rng.random((NP, Hkv, 1, page)).astype(np.float32)
     for b in range(B):
         for j in range(P):
             sl = slice(j * page, (j + 1) * page)
             kp[table[b, j]] = np.asarray(kq[b, :, sl])
             vp[table[b, j]] = np.asarray(vq[b, :, sl])
-            ks[table[b, j]] = np.asarray(ksc[b, :, sl])
-            vs[table[b, j]] = np.asarray(vsc[b, :, sl])
+            ks[table[b, j], :, 0] = np.asarray(ksc[b, :, sl])
+            vs[table[b, j], :, 0] = np.asarray(vsc[b, :, sl])
     deq = (dequantize_kv(kq, ksc), dequantize_kv(vq, vsc))
     return (jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(ks),
             jnp.asarray(vs), jnp.asarray(table, jnp.int32), deq)

@@ -45,7 +45,8 @@ def test_switchable_server_round_robin():
         cfg = reduced_arch(name)
         cfgs[name] = cfg
         m = build_model(cfg)
-        p = m.init(jax.random.key(i))
+        # host-side weights: each load is a real host->device copy
+        p = jax.device_get(m.init(jax.random.key(i)))
         server.register(ServedModel(name=name, model=m,
                                     weights_fn=lambda p=p: p, max_len=40))
     outs = []
@@ -57,8 +58,12 @@ def test_switchable_server_round_robin():
     stats = server.engine.stats
     assert stats["loads"] == 2                       # loaded once each
     assert stats["switches"] >= 6
-    # O(1) switches: orders faster than loads
-    assert (stats["switch_seconds"] / stats["switches"]) < \
+    # O(1) switches: orders faster than loads.  The first batch of each
+    # context waits on that context's load inside its switch (the paper's
+    # partially hidden case), so the O(1) claim is about every later
+    # switch, which finds its context resident.
+    resident = [e["switch_s"] for e in server.log[2:]]
+    assert sum(resident) / len(resident) < \
         (stats["load_seconds"] / stats["loads"])
     server.shutdown()
 
@@ -140,3 +145,59 @@ def test_generate_paged_matches_dense():
     dense = eng.generate(prompt, steps=20)
     paged = eng.generate_paged(prompt, steps=20, page=8)
     np.testing.assert_array_equal(dense, paged)
+
+
+# ---------------------------------------------------------------------------
+# launcher: published widths, host-side weights, bank placement, cache dir
+# ---------------------------------------------------------------------------
+
+def test_build_server_published_widths_loads_host_weights():
+    """``reduced=False`` serves the arch's own widths, and the registered
+    weights live on the host, so a load moves every byte host->device."""
+    from repro.configs import get_arch
+    from repro.launch.serve import build_server
+    server, cfgs = build_server(["supersub-super"], slots=2, max_len=32,
+                                reduced=False)
+    got, want = cfgs["supersub-super"], get_arch("supersub-super")
+    assert (got.d_model, got.num_layers) == (want.d_model, want.num_layers)
+    host = server._served["supersub-super"].weights_fn()
+    leaves = jax.tree.leaves(host)
+    assert leaves and all(isinstance(x, np.ndarray) for x in leaves)
+    server.preload("supersub-super", block=True)
+    assert server.engine.stats["bytes_loaded"] == sum(x.nbytes
+                                                      for x in leaves)
+    server.shutdown()
+
+
+@pytest.mark.parametrize("shards,mesh,want", [
+    (None, None, "single"), (1, None, "single"), (4, None, "logical"),
+    (4, object(), "mesh")])
+def test_bank_placement_names_the_layout(shards, mesh, want):
+    from repro.launch.serve import bank_placement
+    assert bank_placement(shards, mesh) == want
+
+
+def test_shard_mesh_needs_enough_devices():
+    """``--shards N`` with fewer than N devices gets no mesh (the report
+    then says ``logical``) instead of failing."""
+    from repro.launch.serve import shard_mesh
+    assert shard_mesh(None) is None
+    assert shard_mesh(jax.device_count() + 1) is None
+
+
+@pytest.mark.parametrize("env_dir", [None, "/some/cache"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """The environment's cache dir wins and is left to jax; otherwise a
+    fixed path inside the checkout, set in code."""
+    from repro.core import env
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    path, set_in_code = env.compile_cache_dir()
+    if env_dir is None:
+        from pathlib import Path
+        assert set_in_code
+        assert Path(path) == Path(__file__).resolve().parents[1] / ".jax_cache"
+    else:
+        assert (path, set_in_code) == (env_dir, False)

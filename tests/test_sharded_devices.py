@@ -29,7 +29,7 @@ def worker_results():
 @pytest.mark.parametrize("check", [
     "bank_placed_over_mesh", "mesh_streams_bitwise",
     "mesh_prefix_bitwise", "local_read_greedy_streams",
-    "local_read_chunked_streams"])
+    "local_read_chunked_streams", "mesh_kernel_streams"])
 def test_sharded_device_check(worker_results, check):
     res = worker_results.get(check)
     assert res is not None, f"check {check} did not run: {worker_results}"
