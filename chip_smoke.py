@@ -25,8 +25,10 @@ before the last line is printed:
   4. main path    ``repro.launch.serve``'s server and ContinuousScheduler
                   (continuous, paged, page 256, prefill chunk 256,
                   multi-step 4) serving tinyllama-1.1b and supersub-super
-                  at their own widths on 2 context slots; mixed traffic
-                  forces a context change and a shadow-slot load.
+                  at their own widths on 2 context slots; traffic in
+                  bursts makes supersub-super stream into the shadow slot
+                  while tinyllama decodes (a prefetch load that overlaps
+                  execution), then switches back.
 
 ``--four-chips`` runs only the sharded page bank over a 4-device mesh
 (mesh placement, and mesh + ``local_read``) against the one-device paged
@@ -294,6 +296,13 @@ def parity_phase(cfg, seed: int, B: int = 2, S: int = 256,
 
 
 # ---------------------------------------------------------------- phase 4
+def _wait_for(pred, what: str, timeout: float = 600.0) -> None:
+    deadline = time.monotonic() + timeout
+    while not pred():
+        check(time.monotonic() < deadline, f"timed out waiting for {what}")
+        time.sleep(0.005)
+
+
 def main_path_phase(seed: int, reduced: bool = False) -> dict:
     from repro.launch.serve import bank_placement, build_server, serve_report
     from repro.serve.scheduler import ContinuousScheduler
@@ -308,6 +317,7 @@ def main_path_phase(seed: int, reduced: bool = False) -> dict:
           f"{new_tokens} new tokens, max_len {max_len}")
     server, cfgs = build_server(names, slots=2, max_len=max_len,
                                 reduced=reduced)
+    st = server.engine.stats
     try:
         for n in names:
             c = cfgs[n]
@@ -318,12 +328,23 @@ def main_path_phase(seed: int, reduced: bool = False) -> dict:
         with ContinuousScheduler(server, batch_size=8, prefill_chunk=PAGE,
                                  paged=True, page_size=PAGE,
                                  multi_step=4) as sched:
-            futs = []
-            for i, L in enumerate(lens):
-                name = names[(i // 4) % 2]        # bursts of 4, alternating
-                toks = rng.integers(0, cfgs[name].vocab_size, (1, L))
-                futs.append((name, sched.submit(name, toks,
-                                                steps=new_tokens)))
+            # three bursts: 4 tinyllama requests; 8 supersub-super ones
+            # once tinyllama is stepping (their queue outweighs its 4 live
+            # rows past the drain margin, so supersub-super streams into
+            # the shadow slot behind tinyllama's steps); 4 more tinyllama
+            # ones once both are resident (the way back is a select flip)
+            stages = ((MODEL, 4, lambda: True),
+                      (names[1], 8, lambda: sched.stats["steps"] >= 1),
+                      (MODEL, 4, lambda: st["loads"] >= 2))
+            futs, it = [], iter(lens)
+            for name, count, ready in stages:
+                _wait_for(ready, f"the traffic stage before {count} "
+                          f"{name} requests")
+                for _ in range(count):
+                    toks = rng.integers(0, cfgs[name].vocab_size,
+                                        (1, next(it)))
+                    futs.append((name, sched.submit(name, toks,
+                                                    steps=new_tokens)))
             outs = [(name, np.asarray(f.result(timeout=900)))
                     for name, f in futs]
         wall = time.perf_counter() - t0
@@ -338,16 +359,16 @@ def main_path_phase(seed: int, reduced: bool = False) -> dict:
                   f"{name}: output shape {out.shape}")
             check(bool(((out >= 0) & (out < vocab)).all()),
                   f"{name}: token outside vocabulary [0, {vocab})")
-        st = server.engine.stats
         print(f"main path: loads={st['loads']} "
+              f"prefetch_loads={st['prefetch_loads']} "
               f"bytes_loaded={st['bytes_loaded']} "
+              f"hidden_load_seconds={st['hidden_load_seconds']:.6g} "
               f"context_changes={st['context_changes']} "
-              f"prefetch_failures={st['prefetch_failures']} "
-              f"hidden_load_fraction="
-              f"{server.engine.hidden_load_fraction():.6g}", flush=True)
+              f"prefetch_failures={st['prefetch_failures']}", flush=True)
         check(st["bytes_loaded"] > 0, "no weight bytes crossed host->HBM")
-        check(st["loads"] >= 2, "no shadow-slot load (fewer than 2 loads "
-              "on 2 slots)")
+        check(st["prefetch_loads"] >= 1, "no shadow-slot load: every load "
+              "was a demand load")
+        check(st["hidden_load_seconds"] > 0, "no load overlapped execution")
         check(st["context_changes"] >= 1, "no context change happened")
         check(st["prefetch_failures"] == 0,
               f"{st['prefetch_failures']} prefetch(es) failed")
@@ -425,21 +446,26 @@ def four_chip_phase(cfg_name: str, seed: int, reduced: bool = False,
         check(results["mesh"], "mesh placement changed greedy streams")
         # local_read reorders the softmax reduction: identical streams,
         # or teacher-forced logits within LOCAL_READ_BOUND
-        err = _local_read_logit_err(m, params, mesh, lens[0], rng)
+        err, step_s = _local_read_logit_err(m, params, mesh, lens[0], rng)
         print(f"local_read teacher-forced logits rel_l2={err:.6g} "
               f"(bound {LOCAL_READ_BOUND})", flush=True)
+        print("decode step, median wall s after the first: " + ", ".join(
+            f"{k}={v:.6g}" for k, v in step_s.items()), flush=True)
         check(results["local_read"] or err <= LOCAL_READ_BOUND,
               f"local_read: streams differ and logit error {err:.3g} is "
               f"above {LOCAL_READ_BOUND}")
         return {"placement": placement, "local_read_rel_l2": err,
+                "decode_step_s": step_s,
                 **{f"{k}_identical": v for k, v in results.items()}}
     finally:
         server.shutdown()
 
 
-def _local_read_logit_err(m, params, mesh, S: int, rng, T: int = 4):
+def _local_read_logit_err(m, params, mesh, S: int, rng, T: int = 8):
     """Teacher-forced decode logits over the mesh-placed bank: per-shard
-    local reads vs the global gather, same inputs."""
+    local reads vs the global gather, same inputs.  Returns the worst
+    logits rel L2 and each path's median step wall time after its
+    first (compiling) step."""
     from repro.models.layers import BankShard
     axis = mesh.axis_names[0]
     B = 2
@@ -460,17 +486,20 @@ def _local_read_logit_err(m, params, mesh, S: int, rng, T: int = 4):
                         m.page_pool_shardings(pool, mesh, axis))
     pool = jax.jit(m.insert_cache_pages)(pool, rows, tables)
     del rows
-    errs = []
-    for shard in (gather, local):
+    errs, step_s = [], {}
+    for label, shard in (("gather", gather), ("local_read", local)):
         step = jax.jit(lambda p, c, t, pos, tb, s=shard:
                        m.decode_step_pages(p, c, t, pos, tb, shard=s))
-        c, outs = pool, []
+        c, outs, times = pool, [], []
         for i in range(T):
             pos = jnp.full((B,), S + i, jnp.int32)
+            t0 = time.perf_counter()
             logits, c = step(params, c, dec[:, i:i + 1], pos, tables)
             outs.append(np.asarray(logits, np.float32))
+            times.append(time.perf_counter() - t0)
         errs.append(outs)
-    return max(_rel_l2(a, b) for a, b in zip(errs[1], errs[0]))
+        step_s[label] = float(np.median(times[1:]))
+    return max(_rel_l2(a, b) for a, b in zip(errs[1], errs[0])), step_s
 
 
 # -------------------------------------------------------------------- main

@@ -129,7 +129,7 @@ class ContextSwitchEngine:
             "loads": 0, "load_seconds": 0.0, "bytes_loaded": 0,
             "switches": 0, "switch_seconds": 0.0, "evictions": 0,
             "hidden_load_seconds": 0.0, "context_changes": 0,
-            "prefetch_failures": 0,
+            "prefetch_loads": 0, "prefetch_failures": 0,
         })
         # overlap accounting (all guarded by self._lock).  One loader
         # thread => at most one load window open at a time.
@@ -370,6 +370,8 @@ class ContextSwitchEngine:
             self.stats["loads"] += 1
             self.stats["load_seconds"] += dt
             self.stats["bytes_loaded"] += wire_bytes
+            if prefetch:         # a shadow-slot load, not a demand load
+                self.stats["prefetch_loads"] += 1
             # overlap accounting: execution time inside [t0, now] counts
             # this load as *hidden* reconfiguration.  Runs that completed
             # during the window accumulated their clamped overlap in

@@ -150,6 +150,7 @@ def serve_report(server: SwitchableServer, mode: str, wall: float,
         "mean_load_ms": round(1e3 * stats["load_seconds"]
                               / max(stats["loads"], 1), 2),
         "bytes_loaded": stats["bytes_loaded"],
+        "prefetch_loads": stats["prefetch_loads"],
         "prefetch_failures": stats["prefetch_failures"],
         "hidden_load_fraction": round(
             server.engine.hidden_load_fraction(), 3),
@@ -212,9 +213,9 @@ def main(argv=None) -> int:
                          "concurrency per HBM budget (outputs are "
                          "tolerance-close, not bitwise)")
     ap.add_argument("--shards", type=int, default=None,
-                    help="paged mode: partition each engine's KV page "
-                         "bank into this many shards with one free-list "
-                         "each; admission routes a request's pages to "
+                    help="continuous paged mode: partition each "
+                         "engine's KV page bank into this many shards "
+                         "with one free-list each; admission routes a request's pages to "
                          "one shard (prefix hits to the shard holding "
                          "the cached pages, cold admissions to the "
                          "least-loaded shard).  When at least this many "
@@ -265,8 +266,13 @@ def main(argv=None) -> int:
                          "registry snapshot (one JSON line to stderr) "
                          "every SECONDS; 0 disables")
     args = ap.parse_args(argv)
-    if args.shards is not None and (args.shards < 1 or not args.paged):
-        ap.error("--shards needs --paged and a positive shard count")
+    if args.shards is not None and (args.shards < 1 or not args.paged
+                                    or args.mode != "continuous"):
+        # only the continuous step engine lays its bank (and the Pallas
+        # calls beside it) out over a mesh; the other modes' engines
+        # would run one-device kernels on mesh-replicated weights
+        ap.error("--shards needs --mode continuous, --paged and a "
+                 "positive shard count")
     from repro.core import env
     env.set_platform(args.platform)
     if args.x64:

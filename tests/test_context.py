@@ -266,3 +266,19 @@ def test_scheduler_counts_prefetch_that_raises():
     _advisory_prefetch(eng, ["x"])
     assert eng.stats["prefetch_failures"] == 1
     eng.shutdown()
+
+
+def test_prefetch_loads_count_shadow_loads_only():
+    """``ctx.prefetch_loads`` counts completed loads a prefetch started;
+    a demand preload is a load but not a prefetch load."""
+    eng = ContextSwitchEngine(num_slots=2)
+    eng.register(_desc("a", 1.0))
+    eng.register(_desc("b", 2.0))
+    eng.preload("a", block=True)
+    eng.switch("a")
+    assert (eng.stats["loads"], eng.stats["prefetch_loads"]) == (1, 0)
+    futs = eng.prefetch(["b"], limit=1)
+    assert len(futs) == 1
+    futs[0].result(timeout=10)
+    assert (eng.stats["loads"], eng.stats["prefetch_loads"]) == (2, 1)
+    eng.shutdown()

@@ -179,3 +179,34 @@ def test_prefill_beside_mesh_bank_compiles(mesh4, monkeypatch):
     shard = BankShard(mesh4, "model", False)
     assert "tpu_custom_call" in _hlo(
         lambda p, t: m.prefill(p, t, P * PAGE, shard=shard), params, toks)
+
+
+@pytest.mark.parametrize("local_read", [False, True],
+                         ids=["gather", "local_read"])
+def test_decode_step_over_mesh_bank_compiles(mesh4, monkeypatch,
+                                             local_read):
+    """The one sharded combination the launcher allows (``--mode
+    continuous --paged --shards 4`` on 4 chips, and ``StepEngine(mesh=,
+    local_read=)``): a full-width decode step with replicated weights
+    and the page bank split over the mesh."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    from repro.models.layers import BankShard
+    from repro.models.model import build_model
+    _steer_to_tpu(monkeypatch)
+    m = build_model(CFG)
+    rep = NamedSharding(mesh4, PartitionSpec())
+    params = jax.tree.map(lambda s: _sds(rep, s.shape, s.dtype),
+                          m.abstract())
+    np4 = 4 * (NP // 4 + 1)             # whole pages per shard
+    pool = m.init_page_pool(np4, PAGE, abstract=True)
+    caches = jax.tree.map(
+        lambda s, sh: _sds(sh, s.shape, s.dtype), pool,
+        m.page_pool_shardings(pool, mesh4, "model"))
+    tok = _sds(rep, (B, 1), jnp.int32)
+    pos = _sds(rep, (B,), jnp.int32)
+    table = _sds(rep, (B, P), jnp.int32)
+    shard = BankShard(mesh4, "model", local_read)
+    assert "tpu_custom_call" in _hlo(
+        lambda p, c, t, ps, tb: m.decode_step_pages(p, c, t, ps, tb,
+                                                    shard=shard),
+        params, caches, tok, pos, table)
