@@ -314,27 +314,42 @@ class ContextSwitchEngine:
             time.sleep(0.001)
 
     def _do_load(self, desc: ContextDescriptor, prefetch: bool = False):
+        """One load on the loader thread, inside its ``ctx.load`` region
+        (children: ``ctx.load.fetch``, ``ctx.load.put``, ``ctx.load.wait``)."""
+        with self._trace.region("ctx.load", "ctx-loader", ctx=desc.name,
+                                cause="prefetch" if prefetch
+                                else "demand") as region:
+            return self._load(desc, prefetch, region)
+
+    def _load(self, desc: ContextDescriptor, prefetch: bool, region):
         slot = self._claim_slot(desc.name)
+        tr = self._trace
         t0 = self._clock()
         with self._lock:
             self._load_started_at = t0
             self._load_hidden_accum = 0.0
         try:
-            host = desc.weights_fn()
+            with tr.region("ctx.load.fetch", "ctx-loader"):
+                host = desc.weights_fn()
             # stream tensor-by-tensor (the two-step WL programming
             # analogue); device_put is async w.r.t. this thread until the
             # final barrier.
-            if desc.shardings is not None:
-                bufs = jax.tree.map(jax.device_put, host, desc.shardings)
-            elif self.mesh is not None:
-                # replicated over the mesh once, here: weights left on
-                # one device would be re-sent to every device each step
-                bufs = jax.device_put(
-                    host, NamedSharding(self.mesh, PartitionSpec()))
-            else:
-                bufs = jax.tree.map(jax.device_put, host)
-            jax.block_until_ready(bufs)
+            with tr.region("ctx.load.put", "ctx-loader"):
+                if desc.shardings is not None:
+                    bufs = jax.tree.map(jax.device_put, host,
+                                        desc.shardings)
+                elif self.mesh is not None:
+                    # replicated over the mesh once, here: weights left on
+                    # one device would be re-sent to every device each step
+                    bufs = jax.device_put(
+                        host, NamedSharding(self.mesh, PartitionSpec()))
+                else:
+                    bufs = jax.tree.map(jax.device_put, host)
+            with tr.region("ctx.load.wait", "ctx-loader"):
+                jax.block_until_ready(bufs)
             wire_bytes = _nbytes(bufs)        # what actually crossed H2D
+            if tr.enabled:
+                region.set(bytes=wire_bytes)
             if desc.base is not None:
                 # partial reconfiguration: only the delta crossed the wire;
                 # unchanged tensors are shared with the base's device

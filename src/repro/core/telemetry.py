@@ -18,11 +18,14 @@ layer they all share:
     Existing ``stats`` dict call-sites (engines, benches, tests) keep
     working verbatim while the registry is the single store.
   * ``Tracer`` — per-request lifecycle spans/events (submit → queued →
-    admitted → prefill-chunk[i] → first-token → decode ticks → retire,
+    admitted → prefill chunks → first-token → decode ticks → retire,
     plus context load/switch, prefix hit/CoW, page reclaim, spec rounds)
-    in a bounded ring buffer.  Disabled (the default), every record call
-    returns before allocating anything — near-zero overhead, gated by a
-    test.
+    in a bounded ring buffer.  ``Tracer.region`` opens a span around a
+    block of host work and also puts it on the profiler's clock
+    (``jax.profiler.TraceAnnotation``), so a profiler trace shows each
+    layer's host work beside the device planes.  Disabled (the default),
+    every record call returns before allocating anything — near-zero
+    overhead, gated by a test.
   * Chrome trace-event JSON export (``Tracer.chrome_trace`` /
     ``export``), viewable in Perfetto (https://ui.perfetto.dev): one
     track per context slot / pool slot, so a ``load:`` span on one track
@@ -46,6 +49,8 @@ from bisect import bisect_right
 from collections import deque
 from collections.abc import MutableMapping
 from typing import Any, Callable, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["LATENCY_BUCKETS_S", "Histogram", "ManualClock", "MetricRegistry",
            "MetricView", "Telemetry", "Tracer", "safe_ratio"]
@@ -238,6 +243,55 @@ class ManualClock:
         self.t += dt
 
 
+class _NoRegion:
+    """What a disabled tracer's ``region`` returns: one shared object
+    whose ``with`` does nothing."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **args):
+        pass
+
+
+_NO_REGION = _NoRegion()
+
+
+class _Region:
+    """One open ``Tracer.region``: a ring span from enter to exit, and the
+    same span as a ``TraceAnnotation`` (its args become the profiler
+    event's stats)."""
+    __slots__ = ("_tracer", "_name", "_track", "_args", "_ann", "_t0")
+
+    def __init__(self, tracer: "Tracer", name: str, track: str, args: dict):
+        self._tracer = tracer
+        self._name = name
+        self._track = track
+        self._args = args
+
+    def __enter__(self):
+        self._ann = TraceAnnotation(self._name, **self._args)
+        self._ann.__enter__()
+        self._t0 = self._tracer.clock()
+        return self
+
+    def set(self, **args):
+        """Add args known only once the region is open (both sinks)."""
+        self._args.update(args)
+        self._ann.set_metadata(**args)
+
+    def __exit__(self, *exc):
+        t1 = self._tracer.clock()
+        self._ann.__exit__(*exc)
+        self._tracer.span(self._name, self._track, self._t0, t1,
+                          self._args or None)
+        return False
+
+
 class Tracer:
     """Bounded ring buffer of lifecycle events, exportable as Chrome
     trace-event JSON (open at https://ui.perfetto.dev).
@@ -249,10 +303,18 @@ class Tracer:
     over the very timestamps its own accounting used (that is what makes
     the trace-derived hidden-load fraction match the engine's to < 1%).
 
-    Disabled, ``span``/``instant`` return before touching anything —
-    call sites in hot loops additionally guard ``if tracer.enabled:``
-    before building f-string names or args dicts, so a disabled tracer
-    costs one attribute test per record point (allocation-gated by
+    ``region`` is the span of a block of host work (``with``), recorded
+    into the ring AND onto the profiler's clock through
+    ``jax.profiler.TraceAnnotation``: each thread becomes one line of the
+    profile's host plane and the region's args become event stats.  The
+    profiler keeps them only while it traces.  Instants stay
+    ring-only.
+
+    Disabled, ``span``/``instant`` return before touching anything and
+    ``region`` returns one shared no-op object — call sites in hot loops
+    additionally guard ``if tracer.enabled:`` before building f-string
+    names or args dicts, so a disabled tracer costs one attribute test per
+    record point (allocation-gated by
     ``tests/test_telemetry.py::test_disabled_tracer_allocates_nothing``).
     """
 
@@ -291,6 +353,14 @@ class Tracer:
         if len(self._buf) == self.capacity:
             self.dropped += 1
         self._buf.append((track, name, "X", t0, t1 - t0, args))
+
+    def region(self, name: str, track: str, **args):
+        """``with tracer.region("eng.decode", track, rows=4):`` — a span
+        around the block, in the ring and in the profiler's trace.  Track
+        strings at hot call sites are built once, not per call."""
+        if not self.enabled:
+            return _NO_REGION
+        return _Region(self, name, track, args)
 
     # ------------------------------------------------------------- export
     def events(self) -> list[dict]:

@@ -908,7 +908,7 @@ class StepEngine(SlotPool):
             self.stats["cache_evictions"] += len(evicted)
             if self._trace.enabled:
                 self._trace.instant(
-                    "page-reclaim", f"{self.telemetry.prefix}eng",
+                    "page-reclaim", self._track,
                     args={"evicted": len(evicted)})
         return len(evicted)
 
@@ -1178,7 +1178,7 @@ class StepEngine(SlotPool):
         self.stats["prefix_pages_mapped"] += len(retained)
         if self._trace.enabled:
             self._trace.instant(
-                f"prefix-hit:{gens[0].rid}", f"{self.telemetry.prefix}eng",
+                f"prefix-hit:{gens[0].req}", self._track,
                 args={"mapped": len(retained), "cow": cow_src is not None})
         if cow_src is not None:
             self.stats["cow_copies"] += 1
@@ -1268,21 +1268,14 @@ class StepEngine(SlotPool):
         if self._pending[0] is head:
             self._jumps = 0              # the head made progress
 
-    def _note_chunk(self, ps: _PendingPrefill, t0: float, start: int,
-                    end: int, final: bool):
+    def _note_chunk(self, ps: _PendingPrefill, t0: float):
         """Chunk-program telemetry: the admit-to-first-chunk latency
-        sample (admission until its first chunk starts) and the chunk
-        span on this engine's track."""
-        now = self.telemetry.clock()
+        sample (admission until its first chunk starts).  The chunk's
+        span is the ``eng.prefill_chunk`` region around it."""
         if not ps.started:
             ps.started = True
             self.telemetry.observe("admit_to_first_chunk_s",
                                    t0 - ps.gens[0].admitted_at)
-        if self._trace.enabled:
-            self._trace.span(
-                "prefill-chunk", f"{self.telemetry.prefix}eng", t0, now,
-                args={"rid": ps.gens[0].rid, "start": start, "end": end,
-                      "final": final})
 
     def prefill_tick(self, params) -> list[Generation]:
         """Run at most ONE chunk program — the admission budget.  A live
@@ -1315,32 +1308,35 @@ class StepEngine(SlotPool):
         pos = np.full((b,), start, np.int32)
         t0 = self.telemetry.clock()
         try:
-            if ps.cow is not None:
-                # copy-on-write the shared boundary page BEFORE this
-                # request's first write lands in it
-                src, dst = ps.cow
-                self.state = self._call(
-                    self._copy_fn, params, self.state,
-                    jnp.asarray([src], jnp.int32),
-                    jnp.asarray([dst], jnp.int32))
-                ps.cow = None
-                self._pages.release([src])   # copy done: the admission-
-                #                              time pin on the source
-                #                              drops (the index still
-                #                              holds its own reference)
-            if end < S:
-                self.state = self._call(
-                    self._chunk_fn, params, self.state,
-                    jnp.asarray(chunk), jnp.asarray(pos),
-                    jnp.asarray(slots), jnp.asarray(tables))
-                ps.done = end
-                self._note_chunk(ps, t0, start, end, final=False)
-                return []
-            first, self.state = self._call(
-                self._chunk_final_fn, params, self.state,
-                jnp.asarray(chunk), jnp.asarray(pos), jnp.asarray(slots),
-                jnp.asarray(tables), jnp.full((b,), nvalid, jnp.int32),
-                jnp.asarray(ps.rkeys), jnp.asarray(ps.seeded))
+            with self._trace.region("eng.prefill_chunk", self._track,
+                                    req=ps.gens[0].req, start=start,
+                                    end=end, final=end == S):
+                if ps.cow is not None:
+                    # copy-on-write the shared boundary page BEFORE this
+                    # request's first write lands in it
+                    src, dst = ps.cow
+                    self.state = self._call(
+                        self._copy_fn, params, self.state,
+                        jnp.asarray([src], jnp.int32),
+                        jnp.asarray([dst], jnp.int32))
+                    ps.cow = None
+                    self._pages.release([src])   # copy done: the admission-
+                    #                              time pin on the source
+                    #                              drops (the index still
+                    #                              holds its own reference)
+                if end < S:
+                    self.state = self._call(
+                        self._chunk_fn, params, self.state,
+                        jnp.asarray(chunk), jnp.asarray(pos),
+                        jnp.asarray(slots), jnp.asarray(tables))
+                    ps.done = end
+                    self._note_chunk(ps, t0)
+                    return []
+                first, self.state = self._call(
+                    self._chunk_final_fn, params, self.state,
+                    jnp.asarray(chunk), jnp.asarray(pos), jnp.asarray(slots),
+                    jnp.asarray(tables), jnp.full((b,), nvalid, jnp.int32),
+                    jnp.asarray(ps.rkeys), jnp.asarray(ps.seeded))
         except BaseException:
             # a failed chunk abandons the whole request: release its rows
             # so the pool keeps serving (the caller fails the futures).
@@ -1362,7 +1358,7 @@ class StepEngine(SlotPool):
             self._restore_slots([g.slot for g in ps.gens])
             raise
         self._pending.popleft()
-        self._note_chunk(ps, t0, start, end, final=True)
+        self._note_chunk(ps, t0)
         if ps.hit:
             # counters only once the prefix-hit admission committed (its
             # final chunk sampled): an abandoned pending rolled its pages
@@ -1371,8 +1367,8 @@ class StepEngine(SlotPool):
             self.stats["prefix_pages_mapped"] += ps.mapped
             if self._trace.enabled:
                 self._trace.instant(
-                    f"prefix-hit:{ps.gens[0].rid}",
-                    f"{self.telemetry.prefix}eng",
+                    f"prefix-hit:{ps.gens[0].req}",
+                    self._track,
                     args={"mapped": ps.mapped, "cow": ps.had_cow})
             if ps.had_cow:
                 self.stats["cow_copies"] += 1
@@ -1435,23 +1431,30 @@ class StepEngine(SlotPool):
     def _step_live(self, params) -> list[Generation]:
         if self.multi_step > 1 and not self._pending:
             return self._step_multi(params)
-        t0 = self.telemetry.clock()
-        nxt, self.state = self._call(self._step_fn, params, self.state,
-                                     jnp.asarray(self._live))
-        nxt = np.asarray(nxt)
-        now = self.telemetry.clock()
-        self.stats["host_ticks"] += 1
-        self.stats["device_steps"] += 1
-        stepped = []
-        for s in range(self.batch_size):
-            g = self.slots[s]
-            if g is None or not self._live[s]:
-                continue                  # empty, or reserved mid-prefill
-            g.tokens.append(int(nxt[s]))
-            stepped.append(g)
-        self.stats["tokens_out"] += len(stepped)
-        self._note_tick(t0, now, 1, len(stepped))
-        return self._retire_done(stepped)
+        tr = self._trace
+        with tr.region("eng.decode", self._track) as reg:
+            with tr.region("eng.dispatch", self._track):
+                t0 = self.telemetry.clock()
+                nxt, self.state = self._call(self._step_fn, params,
+                                             self.state,
+                                             jnp.asarray(self._live))
+            with tr.region("eng.sync", self._track):
+                nxt = np.asarray(nxt)
+            now = self.telemetry.clock()
+            self.stats["host_ticks"] += 1
+            self.stats["device_steps"] += 1
+            stepped = []
+            for s in range(self.batch_size):
+                g = self.slots[s]
+                if g is None or not self._live[s]:
+                    continue              # empty, or reserved mid-prefill
+                g.tokens.append(int(nxt[s]))
+                stepped.append(g)
+            self.stats["tokens_out"] += len(stepped)
+            self._note_tick(t0, now, 1, len(stepped))
+            if tr.enabled:
+                reg.set(steps=1, rows=len(stepped))
+            return self._retire_done(stepped)
 
     def _step_multi(self, params) -> list[Generation]:
         """The fused tick: ship every live row's remaining-token budget
@@ -1459,34 +1462,41 @@ class StepEngine(SlotPool):
         steps, read back ONE (tokens, n_steps) pair.  Exactly one host
         sync per call regardless of how many steps committed."""
         B = self.batch_size
-        rem = np.zeros((B,), np.int32)
-        budget = np.zeros((B,), np.int32)
-        for s in range(B):
-            g = self.slots[s]
-            if g is None or not self._live[s]:
-                continue
-            rem[s] = g.remaining
-            budget[s] = (len(g.pages) * self.page_size
-                         if self.paged and g.pages else self.max_len)
-        t0 = self.telemetry.clock()
-        toks, n, self.state = self._call(
-            self._mstep_fn, params, self.state, jnp.asarray(self._live),
-            jnp.asarray(rem), jnp.asarray(budget))
-        toks = np.asarray(toks)
-        n = int(n)
-        now = self.telemetry.clock()
-        self.stats["host_ticks"] += 1
-        self.stats["device_steps"] += n
-        stepped = []
-        for s in range(B):
-            g = self.slots[s]
-            if g is None or not self._live[s]:
-                continue
-            g.tokens.extend(int(t) for t in toks[s, :n])
-            stepped.append(g)
-        self.stats["tokens_out"] += n * len(stepped)
-        self._note_tick(t0, now, n, len(stepped))
-        return self._retire_done(stepped)
+        tr = self._trace
+        with tr.region("eng.decode", self._track) as reg:
+            with tr.region("eng.dispatch", self._track):
+                rem = np.zeros((B,), np.int32)
+                budget = np.zeros((B,), np.int32)
+                for s in range(B):
+                    g = self.slots[s]
+                    if g is None or not self._live[s]:
+                        continue
+                    rem[s] = g.remaining
+                    budget[s] = (len(g.pages) * self.page_size
+                                 if self.paged and g.pages else self.max_len)
+                t0 = self.telemetry.clock()
+                toks, n, self.state = self._call(
+                    self._mstep_fn, params, self.state,
+                    jnp.asarray(self._live), jnp.asarray(rem),
+                    jnp.asarray(budget))
+            with tr.region("eng.sync", self._track):
+                toks = np.asarray(toks)
+                n = int(n)
+            now = self.telemetry.clock()
+            self.stats["host_ticks"] += 1
+            self.stats["device_steps"] += n
+            stepped = []
+            for s in range(B):
+                g = self.slots[s]
+                if g is None or not self._live[s]:
+                    continue
+                g.tokens.extend(int(t) for t in toks[s, :n])
+                stepped.append(g)
+            self.stats["tokens_out"] += n * len(stepped)
+            self._note_tick(t0, now, n, len(stepped))
+            if tr.enabled:
+                reg.set(steps=n, rows=len(stepped))
+            return self._retire_done(stepped)
 
 
 # ---------------------------------------------------------------------------

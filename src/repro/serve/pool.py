@@ -31,7 +31,7 @@ from __future__ import annotations
 
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import numpy as np
@@ -39,10 +39,17 @@ import numpy as np
 from repro.serve.telemetry import Telemetry
 
 
+class RowMeta(NamedTuple):
+    """A scheduler's payload for one admitted row: the request's id,
+    unique across the scheduler, and the row's index in the request."""
+    req: int
+    row: int
+
+
 @dataclass
 class Generation:
     """Host-side handle for one admitted request (one slot row)."""
-    rid: int
+    rid: int                              # this engine's row counter
     prompt_len: int
     max_new: int
     slot: int = -1
@@ -56,6 +63,9 @@ class Generation:
     submitted_at: Optional[float] = None  # scheduler enqueue (if known)
     admitted_at: Optional[float] = None   # slot granted
     first_token_at: Optional[float] = None
+    # the request id the row's spans carry: the scheduler's (``RowMeta``)
+    # when one admitted it, else ``rid``
+    req: int = -1
 
     @property
     def remaining(self) -> int:
@@ -663,6 +673,7 @@ class SlotPool:
         # engine a scoped ``eng.<i>.`` namespace.
         self.telemetry = telemetry if telemetry is not None else Telemetry()
         self._trace = self.telemetry.tracer
+        self._track = f"{self.telemetry.prefix}eng"
         # Engine-lifetime tick counters (NOT cleared by ``reset``;
         # benches take deltas): ``host_ticks`` counts decode round-trips
         # to the device, ``device_steps`` the decode steps those trips
@@ -764,10 +775,12 @@ class SlotPool:
         now = self.telemetry.clock()
         gens = []
         for i, s in enumerate(slots):
+            meta = metas[i] if metas else None
             g = Generation(rid=self._rid, prompt_len=prompt_len,
-                           max_new=max_new, slot=s,
-                           meta=metas[i] if metas else None,
-                           submitted_at=submitted_at, admitted_at=now)
+                           max_new=max_new, slot=s, meta=meta,
+                           submitted_at=submitted_at, admitted_at=now,
+                           req=meta.req if isinstance(meta, RowMeta)
+                           else self._rid)
             self._rid += 1
             self.stats["admitted_rows"] += 1
             if submitted_at is not None:
@@ -793,24 +806,21 @@ class SlotPool:
         self.telemetry.observe("ttft_s", now - ref)
         if self._trace.enabled:
             self._trace.instant(
-                f"first-token:{g.rid}",
+                f"first-token:{g.req}",
                 f"{self.telemetry.prefix}pool{g.slot}", ts=now)
 
     def _note_tick(self, t0: float, now: float, nsteps: int, nrows: int):
         """Per-tick telemetry: the per-token latency sample (tick
-        duration amortized over the decode steps it committed), the
+        duration amortized over the decode steps it committed) and the
         host-side inter-commit stall (gap between the previous tick's
-        commit and this tick's start — scheduler/bookkeeping overhead),
-        and the tick span."""
+        commit and this tick's start — scheduler/bookkeeping overhead).
+        The tick's span is the ``eng.decode`` region around it."""
         if nrows and nsteps:
             self.telemetry.observe("token_latency_s", (now - t0) / nsteps)
         last = self._last_commit_at
         if last is not None and t0 > last:
             self.telemetry.observe("decode_stall_s", t0 - last)
         self._last_commit_at = now
-        if self._trace.enabled:
-            self._trace.span("tick", f"{self.telemetry.prefix}eng",
-                             t0, now, args={"steps": nsteps, "rows": nrows})
 
     # ----------------------------------------------------------- retirement
     def _retire_done(self, gens: list[Generation]) -> list[Generation]:
@@ -833,7 +843,7 @@ class SlotPool:
                     # one span per request on its slot's track:
                     # admitted -> retired (Perfetto: slot occupancy).
                     self._trace.span(
-                        f"req:{g.rid}",
+                        f"req:{g.req}",
                         f"{self.telemetry.prefix}pool{g.slot}",
                         g.admitted_at, now,
                         args={"tokens": len(g.tokens),

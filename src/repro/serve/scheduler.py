@@ -37,6 +37,7 @@ forward pass; everything else is served back-to-back after a single switch.
 """
 from __future__ import annotations
 
+import itertools
 import math
 import threading
 import time
@@ -49,6 +50,7 @@ import jax
 import numpy as np
 
 from repro.serve.engine import EngineKey
+from repro.serve.pool import RowMeta
 from repro.serve.speculative import SpecKey
 from repro.serve.telemetry import Telemetry, safe_ratio
 
@@ -78,6 +80,32 @@ class _Request:
     future: Future
     submitted_at: float
     explicit_seed: bool = False    # caller pinned `seed` (reproducible row)
+    rid: int = -1                  # request id (ContinuousScheduler)
+
+
+class RequestFuture(Future):
+    """What ``ContinuousScheduler.submit`` returns: the request's output,
+    its id (``req``: unique across the scheduler, carried by every span of
+    the request), and its stamps on the program's clock, filled in before
+    the result is set:
+
+      * ``submitted_at`` — enqueued by ``submit``
+      * ``admitted_at``  — its first row got a slot
+      * ``first_token_at`` — its first row's first token reached the host
+      * ``done_at``      — the request resolved
+      * ``tokens``       — output tokens per row
+
+    so time to first token is ``first_token_at - submitted_at`` and time
+    per output token ``(done_at - first_token_at) / (tokens - 1)``."""
+
+    def __init__(self, req: int, submitted_at: float):
+        super().__init__()
+        self.req = req
+        self.submitted_at = submitted_at
+        self.admitted_at: Optional[float] = None
+        self.first_token_at: Optional[float] = None
+        self.done_at: Optional[float] = None
+        self.tokens = 0
 
 
 class SwitchScheduler:
@@ -460,9 +488,9 @@ class ContinuousScheduler:
             raise ValueError("share_bank needs paged=True")
         self.share_bank = share_bank
         self._queues: dict[str, deque[_Request]] = defaultdict(deque)
-        self._inflight: dict[int, _Inflight] = {}
-        self._inflight_seq = 0          # monotonic key: ids recycle, this
-        self._cv = threading.Condition()                      # never does
+        self._inflight: dict[int, _Inflight] = {}   # by request id
+        self._req_ids = itertools.count()   # request ids, never reused
+        self._cv = threading.Condition()
         self._stopping = False
         self._drain = True
         self._thread: Optional[threading.Thread] = None
@@ -489,8 +517,9 @@ class ContinuousScheduler:
 
     # ------------------------------------------------------------- client
     def submit(self, name: str, tokens, steps: int = 1,
-               seed: Optional[int] = None) -> Future:
-        """Enqueue one request; resolves to the (b, steps) output array.
+               seed: Optional[int] = None) -> RequestFuture:
+        """Enqueue one request; resolves to the (b, steps) output array
+        (the future also carries the request's id and stamps).
 
         ``seed`` pins the request's sampling draws to its own per-slot key
         column (``DecodeState.rkey``), folded with each token's position:
@@ -516,12 +545,12 @@ class ContinuousScheduler:
             raise ValueError(f"prompt {S} + {steps} steps (+{slack} "
                              f"speculative slack) exceeds max_len "
                              f"{sm.max_len}")
-        fut: Future = Future()
+        rid, now = next(self._req_ids), self._clock()
         req = _Request(name=name, tokens=tokens, steps=steps,
                        seed=self.server.next_seed() if seed is None
                        else seed,
-                       future=fut, submitted_at=self._clock(),
-                       explicit_seed=seed is not None)
+                       future=RequestFuture(rid, now), submitted_at=now,
+                       explicit_seed=seed is not None, rid=rid)
         with self._cv:
             if self._stopping:
                 raise RuntimeError("scheduler is stopped")
@@ -531,8 +560,8 @@ class ContinuousScheduler:
             self._cv.notify()
         if self._trace.enabled:
             self._trace.instant(f"submit:{name}", "sched",
-                                ts=req.submitted_at)
-        return fut
+                                ts=req.submitted_at, args={"req": rid})
+        return req.future
 
     def _note_queued_locked(self):
         """Refresh the queued-requests gauge; caller holds ``_cv``."""
@@ -697,17 +726,20 @@ class ContinuousScheduler:
 
     def _loop(self):
         cur: Optional[str] = None
+        tr = self._trace
         while True:
             with self._cv:
                 if not self._has_work():
                     if self._stopping:
                         return
-                    self._cv.wait(timeout=0.05)
+                    with tr.region("sched.wait", "sched"):
+                        self._cv.wait(timeout=0.05)
                     continue
                 if self._stopping and not self._drain:
                     return
             try:
-                cur = self._tick(cur)
+                with tr.region("sched.tick", "sched"):
+                    cur = self._tick(cur)
             except BaseException as e:
                 # fail the context the tick was ACTING on when it raised
                 # (_tick may have switched away from `cur` first — failing
@@ -772,11 +804,13 @@ class ContinuousScheduler:
             finished = eng.step(None)         # params come from run_step
             self.stats["steps"] += 1
             self.stats["busy_seconds"] += self._clock() - t0
-            self._resolve(finished)
+            with self._trace.region("sched.resolve", "sched"):
+                self._resolve(finished)
             if self.spec_adaptive and cur in self.draft:
                 self._adapt_k(cur, eng)
         else:
-            time.sleep(0.0005)                # waiting on a load/queue
+            with self._trace.region("sched.idle_sleep", "sched"):
+                time.sleep(0.0005)            # waiting on a load/queue
         # starvation-guard bookkeeping: stamp contexts left holding frozen
         # rows; the stamp ages their pressure until they are resumed
         mark = self._clock()
@@ -818,8 +852,10 @@ class ContinuousScheduler:
     def _activate(self, name: str) -> str:
         t0 = self._clock()
         was_resident = self.server.engine.policy.holds(name)
-        self.server.engine.preload(name)
-        self.server.engine.switch(name, wait=True)
+        with self._trace.region("sched.activate", "sched", ctx=name,
+                                resident=was_resident):
+            self.server.engine.preload(name)
+            self.server.engine.switch(name, wait=True)
         if not was_resident:
             self._note_load_cost(name, self._clock() - t0)
         return name
@@ -860,10 +896,7 @@ class ContinuousScheduler:
                 req = q.popleft()
                 self._note_queued_locked()
             b = req.tokens.shape[0]
-            inf = _Inflight(req=req, need=b)
-            key = self._inflight_seq
-            self._inflight_seq += 1
-            self._inflight[key] = inf
+            self._inflight[req.rid] = _Inflight(req=req, need=b)
             # explicitly seeded requests pin each row to its own key:
             # split() derives per-row keys deterministically, so the same
             # (seed, prompt) resubmission reproduces row-for-row
@@ -871,36 +904,45 @@ class ContinuousScheduler:
             if req.explicit_seed:
                 seeds = list(jax.random.split(
                     jax.random.PRNGKey(req.seed), b))
-            try:
-                gens = eng.admit(None, req.tokens, max_new=req.steps,
-                                 metas=[(key, i) for i in range(b)],
-                                 seeds=seeds,
-                                 submitted_at=req.submitted_at)
-            except BaseException as e:
-                del self._inflight[key]
-                self.stats["rejected_requests"] += 1
-                req.future.set_exception(e)
-                continue
-            self.stats["admitted_rows"] += b
-            self.stats["admitted_requests"] += 1
-            self._resolve([g for g in gens if g.done])
+            with self._trace.region("sched.admit", "sched", req=req.rid):
+                try:
+                    gens = eng.admit(
+                        None, req.tokens, max_new=req.steps,
+                        metas=[RowMeta(req.rid, i) for i in range(b)],
+                        seeds=seeds, submitted_at=req.submitted_at)
+                except BaseException as e:
+                    del self._inflight[req.rid]
+                    self.stats["rejected_requests"] += 1
+                    req.future.set_exception(e)
+                    continue
+                self.stats["admitted_rows"] += b
+                self.stats["admitted_requests"] += 1
+                self._resolve([g for g in gens if g.done])
 
     def _resolve(self, finished):
+        """Set the futures of requests whose every row finished, with the
+        request's stamps (taken from its first row) on the future."""
         for g in finished:
             key, row = g.meta
             inf = self._inflight.get(key)
             if inf is None:
                 continue
-            inf.rows[row] = g.tokens
+            inf.rows[row] = g
             if len(inf.rows) == inf.need:
                 del self._inflight[key]
-                out = np.stack([np.asarray(inf.rows[i], np.int32)
-                                for i in range(inf.need)])
-                if not inf.req.future.done():
-                    inf.req.future.set_result(out)
+                rows = [inf.rows[i] for i in range(inf.need)]
+                out = np.stack([np.asarray(r.tokens, np.int32)
+                                for r in rows])
+                fut = inf.req.future
+                if not fut.done():
+                    now = self._clock()
+                    fut.admitted_at = rows[0].admitted_at
+                    fut.first_token_at = rows[0].first_token_at
+                    fut.done_at = now
+                    fut.tokens = out.shape[1]
+                    fut.set_result(out)
                     self.telemetry.observe(
-                        "request_latency_s",
-                        self._clock() - inf.req.submitted_at,
+                        "request_latency_s", now - inf.req.submitted_at,
                         doc="seconds between submit and future resolution")
 
     def _fail_context(self, cur: Optional[str], exc: BaseException):

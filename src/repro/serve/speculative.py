@@ -1096,7 +1096,7 @@ class SpecEngine(SlotPool):
             self.stats["cow_copies"] += 1
         if self._trace.enabled:
             self._trace.instant(
-                f"prefix-hit:{gens[0].rid}", f"{self.telemetry.prefix}eng",
+                f"prefix-hit:{gens[0].req}", self._track,
                 args={"mapped": len(retained), "cow": cow_src is not None})
         if self._retire_done(gens):
             self._salt_admit_key()
@@ -1160,20 +1160,23 @@ class SpecEngine(SlotPool):
         jchunk = jnp.asarray(chunk)
         t0 = self.telemetry.clock()
         try:
-            self.state = self._call(
-                "draft", self._chunk_d_fn, params, self.state, jchunk,
-                pos, jnp.asarray(ps.d_tables), nv)
-            if end < S:
+            with self._trace.region("eng.prefill_chunk", self._track,
+                                    req=ps.gens[0].req, start=start,
+                                    end=end, final=end == S):
                 self.state = self._call(
-                    "target", self._chunk_t_fn, params, self.state,
-                    jchunk, pos, jnp.asarray(ps.t_tables), nv)
-                ps.done = end
-                self._note_chunk(ps, t0, start, end, final=False)
-                return []
-            slots = jnp.asarray([g.slot for g in ps.gens], jnp.int32)
-            first, self.state = self._call(
-                "target", self._chunk_t_final_fn, params, self.state,
-                jchunk, pos, slots, jnp.asarray(ps.t_tables), nv)
+                    "draft", self._chunk_d_fn, params, self.state, jchunk,
+                    pos, jnp.asarray(ps.d_tables), nv)
+                if end < S:
+                    self.state = self._call(
+                        "target", self._chunk_t_fn, params, self.state,
+                        jchunk, pos, jnp.asarray(ps.t_tables), nv)
+                    ps.done = end
+                    self._note_chunk(ps, t0)
+                    return []
+                slots = jnp.asarray([g.slot for g in ps.gens], jnp.int32)
+                first, self.state = self._call(
+                    "target", self._chunk_t_final_fn, params, self.state,
+                    jchunk, pos, slots, jnp.asarray(ps.t_tables), nv)
         except BaseException:
             # a failed chunk abandons the whole request: release its rows
             # so the pool keeps serving (the caller fails the futures).
@@ -1193,7 +1196,7 @@ class SpecEngine(SlotPool):
             self._restore_slots([g.slot for g in ps.gens])
             raise
         self._pending.popleft()
-        self._note_chunk(ps, t0, start, end, final=True)
+        self._note_chunk(ps, t0)
         first = np.asarray(first)
         tok_now = self.telemetry.clock()
         for i, g in enumerate(ps.gens):
@@ -1245,62 +1248,64 @@ class SpecEngine(SlotPool):
                     remaining[s] = g.remaining
             live = jnp.asarray(self._live)
             fns = self._programs(self.k)
-            t0 = self.telemetry.clock()
-            props, dlogits, self.state = self._call(
-                "draft", fns["roll"], params, self.state, live)
-            if self.tree_width == 1:
-                toks, m, self.state = self._call(
-                    "target", fns["verify"], params, self.state, props,
-                    dlogits, live, jnp.asarray(remaining))
-            else:
-                (toks, m, alt_depth, alt_tok, rpos,
-                 self.state) = self._call(
-                    "target", fns["verify"], params, self.state, props,
-                    dlogits, live, jnp.asarray(remaining))
-                # the target column repaired itself inside the verify
-                # program; the draft column repairs here, host-gated (the
-                # common all-chain rounds skip the extra draft step)
-                alt_live = self._live & (np.asarray(alt_depth) > 0)
-                if alt_live.any():
-                    self.state = self._call(
-                        "draft", self._repair_d_fn, params, self.state,
-                        alt_tok[:, None], rpos, jnp.asarray(alt_live))
-                    self.stats["draft_steps"] += 1
-            toks, m = np.asarray(toks), np.asarray(m)
-            now = self.telemetry.clock()
-            stepped = []
-            committed = 0
-            reg = self.telemetry.registry
-            for s in range(self.batch_size):
-                g = self.slots[s]
-                if g is None or not self._live[s]:
-                    continue              # empty, or reserved mid-prefill
-                new = [int(x) for x in toks[s, :m[s]]]
-                if self.eos_id is not None and self.eos_id in new:
-                    new = new[:new.index(self.eos_id) + 1]
-                g.tokens.extend(new)
-                committed += len(new)
-                reg.observe("spec_accept_len", float(len(new)),
-                            buckets=SPEC_ACCEPT_BUCKETS,
-                            doc="tokens committed per row per "
-                                "speculative round")
-                stepped.append(g)
-            self.stats["rounds"] += 1
-            self.stats["row_rounds"] += len(stepped)
-            self.stats["draft_steps"] += self.k + 1
-            self.stats["committed_tokens"] += committed
-            self.stats["tokens_out"] += committed
-            # per-token latency: the round amortizes over the tokens each
-            # row committed (1..K+1); the round itself is not a decode
-            # tick.
-            self._note_tick(t0, now, safe_ratio(committed, len(stepped)),
-                            len(stepped))
-            if self._trace.enabled:
-                self._trace.instant(
-                    "spec-round", f"{self.telemetry.prefix}eng", ts=now,
-                    args={"committed": committed, "rows": len(stepped),
-                          "k": self.k, "tree_width": self.tree_width,
-                          "accepted": [int(x) for x in m if x]})
-            return finished + self._retire_done(stepped)
+            with self._trace.region("eng.decode", self._track) as rnd:
+                t0 = self.telemetry.clock()
+                props, dlogits, self.state = self._call(
+                    "draft", fns["roll"], params, self.state, live)
+                if self.tree_width == 1:
+                    toks, m, self.state = self._call(
+                        "target", fns["verify"], params, self.state, props,
+                        dlogits, live, jnp.asarray(remaining))
+                else:
+                    (toks, m, alt_depth, alt_tok, rpos,
+                     self.state) = self._call(
+                        "target", fns["verify"], params, self.state, props,
+                        dlogits, live, jnp.asarray(remaining))
+                    # the target column repaired itself inside the verify
+                    # program; the draft column repairs here, host-gated (the
+                    # common all-chain rounds skip the extra draft step)
+                    alt_live = self._live & (np.asarray(alt_depth) > 0)
+                    if alt_live.any():
+                        self.state = self._call(
+                            "draft", self._repair_d_fn, params, self.state,
+                            alt_tok[:, None], rpos, jnp.asarray(alt_live))
+                        self.stats["draft_steps"] += 1
+                toks, m = np.asarray(toks), np.asarray(m)
+                now = self.telemetry.clock()
+                stepped = []
+                committed = 0
+                reg = self.telemetry.registry
+                for s in range(self.batch_size):
+                    g = self.slots[s]
+                    if g is None or not self._live[s]:
+                        continue              # empty, or reserved mid-prefill
+                    new = [int(x) for x in toks[s, :m[s]]]
+                    if self.eos_id is not None and self.eos_id in new:
+                        new = new[:new.index(self.eos_id) + 1]
+                    g.tokens.extend(new)
+                    committed += len(new)
+                    reg.observe("spec_accept_len", float(len(new)),
+                                buckets=SPEC_ACCEPT_BUCKETS,
+                                doc="tokens committed per row per "
+                                    "speculative round")
+                    stepped.append(g)
+                self.stats["rounds"] += 1
+                self.stats["row_rounds"] += len(stepped)
+                self.stats["draft_steps"] += self.k + 1
+                self.stats["committed_tokens"] += committed
+                self.stats["tokens_out"] += committed
+                # per-token latency: the round amortizes over the tokens each
+                # row committed (1..K+1); the round itself is not a decode
+                # tick.
+                self._note_tick(t0, now, safe_ratio(committed, len(stepped)),
+                                len(stepped))
+                if self._trace.enabled:
+                    rnd.set(rows=len(stepped))
+                    self._trace.instant(
+                        "spec-round", self._track, ts=now,
+                        args={"committed": committed, "rows": len(stepped),
+                              "k": self.k, "tree_width": self.tree_width,
+                              "accepted": [int(x) for x in m if x]})
+                return finished + self._retire_done(stepped)
         finally:
             self._bank_push()

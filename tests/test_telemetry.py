@@ -174,13 +174,17 @@ def test_simulate_dynamic_emits_live_engine_keys():
 def test_disabled_tracer_allocates_nothing():
     """Disabled, span/instant must return without allocating — the hot
     decode loop pays one attribute test per record point and nothing
-    else (no tuple, no deque append, no args dict)."""
+    else (no tuple, no deque append, no args dict).  ``region`` returns
+    one shared no-op object, whose ``with`` records nothing."""
     import tracemalloc
     tr = Tracer(enabled=False)
-    name, track = "tick", "eng"
+    name, track = "eng.decode", "eng"
     for _ in range(4):                         # warm any lazy setup
         tr.span(name, track, 0.0, 1.0)
         tr.instant(name, track, ts=0.0)
+        with tr.region(name, track):
+            pass
+    assert tr.region(name, track) is tr.region("sched.tick", "sched")
     # tracemalloc attributes every allocation to its source line, so
     # background-thread noise cannot produce a false positive: any
     # telemetry.py allocation during the loop is a real per-call cost
@@ -190,6 +194,8 @@ def test_disabled_tracer_allocates_nothing():
         for _ in range(1000):
             tr.span(name, track, 0.0, 1.0)
             tr.instant(name, track, ts=0.0)
+            with tr.region(name, track) as reg:
+                reg.set(rows=4)
         snap2 = tracemalloc.take_snapshot()
     finally:
         tracemalloc.stop()
@@ -248,7 +254,8 @@ def test_chrome_trace_schema(tiny_lm):
         else:
             assert e["s"] == "t"
     kinds = {e["name"].split(":")[0] for e in data}
-    assert {"tick", "first-token", "req"} <= kinds
+    assert {"eng.decode", "eng.dispatch", "eng.sync", "first-token",
+            "req"} <= kinds
 
 
 # ---------------------------------------------------------------------------
