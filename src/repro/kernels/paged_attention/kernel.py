@@ -4,15 +4,19 @@ cache whose rows live as PAGES of one shared pool.
 Extends ``decode_attention``'s design along the axis the paged slot pool
 needs: the per-request ``(B,)`` position vector in SMEM grows a per-row
 ``(B, P)`` *page table*, also scalar-prefetched.  The cache operand is no
-longer a ``(B, Hkv, S, hd)`` row bank but the shared page pool
-``(NP, Hkv, page, hd)``, and the kernel's BlockSpec index map reads the
-page table to decide which pool page each grid step DMAs:
+longer a ``(B, Hkv, S, hd)`` row bank but the model's stacked page pools
+``(R, NP, Hkv, page, hd)``, one per layer, and the kernel's BlockSpec
+index map reads the page table and the scalar-prefetched layer index to
+decide which pool page each grid step DMAs:
 
-    lambda b, h, j, pos, pt: (pt[b, j], h, 0, 0)
+    lambda b, h, j, pos, pt, lay: (lay[0], pt[b, j], h, 0, 0)
 
-so row b's j-th cache tile is *its own* j-th page, wherever the host
-allocator placed it — pages of one request need not be contiguous, and
-pages of different requests interleave freely in the pool.
+so row b's j-th cache tile is *its own* j-th page of layer ``lay[0]``,
+wherever the host allocator placed it — pages of one request need not be
+contiguous, and pages of different requests interleave freely in the
+pool.  Reading the layer through the index map (a squeezed leading block
+dim) lets the layer scan hand the kernel the whole donated bank: no
+layer's pool is ever sliced out of it.
 
 Everything else is the proven flash-decode structure:
 
@@ -41,8 +45,14 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _paged_decode_kernel(pos_ref, pt_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_scr, l_scr, acc_scr, *, scale: float,
+def _layer_operand(layer):
+    """The layer index as the (1,) int32 scalar-prefetch operand the
+    page index maps read (``lay[0]``)."""
+    return jnp.broadcast_to(jnp.asarray(layer, jnp.int32), (1,))
+
+
+def _paged_decode_kernel(pos_ref, pt_ref, lay_ref, q_ref, k_ref, v_ref,
+                         o_ref, m_scr, l_scr, acc_scr, *, scale: float,
                          page: int, np_row: int):
     b = pl.program_id(0)
     j = pl.program_id(2)
@@ -81,8 +91,8 @@ def _paged_decode_kernel(pos_ref, pt_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def _paged_decode_kernel_q(pos_ref, pt_ref, q_ref, k_ref, v_ref, ks_ref,
-                           vs_ref, o_ref, m_scr, l_scr, acc_scr, *,
+def _paged_decode_kernel_q(pos_ref, pt_ref, lay_ref, q_ref, k_ref, v_ref,
+                           ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr, *,
                            scale: float, page: int, np_row: int):
     """int8-bank variant: k/v tiles are int8 codes and two extra
     (1, 1, 1, page) scale tiles ride the SAME page-table index map, so the
@@ -129,27 +139,29 @@ def _paged_decode_kernel_q(pos_ref, pt_ref, q_ref, k_ref, v_ref, ks_ref,
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def paged_decode_attention_kernel(q, k_pages, v_pages, page_table, pos, *,
-                                  scale: float | None = None,
+def paged_decode_attention_kernel(q, k_pages, v_pages, page_table, pos,
+                                  layer, *, scale: float | None = None,
                                   k_scale=None, v_scale=None,
                                   interpret: bool = False) -> jax.Array:
-    """q: (B, Hkv, G, hd); k_pages/v_pages: (NP, Hkv, page, hd) shared
-    pool; page_table: (B, P) int32 pool-page ids (dead entries must hold
-    a valid index — the park page); pos: (B,) int32 valid length per
-    row.  ``k_scale``/``v_scale`` ((NP, Hkv, 1, page) f32) select the
-    int8 bank path: codes dequantize inside the kernel."""
+    """q: (B, Hkv, G, hd); k_pages/v_pages: (R, NP, Hkv, page, hd)
+    stacked pools, one per layer; page_table: (B, P) int32 pool-page ids
+    (dead entries must hold a valid index — the park page); pos: (B,)
+    int32 valid length per row; layer: () int32, the pool read.
+    ``k_scale``/``v_scale`` ((R, NP, Hkv, 1, page) f32) select the int8
+    bank path: codes dequantize inside the kernel."""
     B, Hkv, G, hd = q.shape
-    NP, _, page, _ = k_pages.shape
+    page = k_pages.shape[-2]
     P = page_table.shape[1]
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
     quantized = k_scale is not None
 
-    page_spec = pl.BlockSpec((1, 1, page, hd),
-                             lambda b, h, j, pos, pt: (pt[b, j], h, 0, 0))
+    page_spec = pl.BlockSpec(
+        (None, 1, 1, page, hd),
+        lambda b, h, j, pos, pt, lay: (lay[0], pt[b, j], h, 0, 0))
     in_specs = [
         pl.BlockSpec((1, 1, G, hd),
-                     lambda b, h, j, pos, pt: (b, h, 0, 0)),
+                     lambda b, h, j, pos, pt, lay: (b, h, 0, 0)),
         page_spec,
         page_spec,
     ]
@@ -159,20 +171,21 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, page_table, pos, *,
                                    page=page, np_row=P)
         # (1, page) trailing block: legal for the TPU tiling (a unit
         # second-minor dim equal to the array's), unlike (1, 1, page)
-        # blocks over an (NP, Hkv, page) leaf
+        # blocks over an (R, NP, Hkv, page) leaf
         scale_spec = pl.BlockSpec(
-            (1, 1, 1, page), lambda b, h, j, pos, pt: (pt[b, j], h, 0, 0))
+            (None, 1, 1, 1, page),
+            lambda b, h, j, pos, pt, lay: (lay[0], pt[b, j], h, 0, 0))
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
     else:
         kernel = functools.partial(_paged_decode_kernel, scale=scale,
                                    page=page, np_row=P)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, Hkv, P),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, G, hd),
-                               lambda b, h, j, pos, pt: (b, h, 0, 0)),
+                               lambda b, h, j, pos, pt, lay: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
@@ -188,12 +201,12 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, page_table, pos, *,
         interpret=interpret,
         name="paged_decode_attention",
     )(jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,)),
-      jnp.asarray(page_table, jnp.int32), *operands)
+      jnp.asarray(page_table, jnp.int32), _layer_operand(layer), *operands)
 
 
-def _paged_verify_kernel(pos_ref, pt_ref, anc_ref, q_ref, k_ref, v_ref,
-                         kb_ref, vb_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                         scale: float, tree: bool, page: int, np_row: int,
+def _paged_verify_kernel(pos_ref, pt_ref, anc_ref, lay_ref, q_ref, k_ref,
+                         v_ref, kb_ref, vb_ref, o_ref, m_scr, l_scr, acc_scr,
+                         *, scale: float, tree: bool, page: int, np_row: int,
                          K: int, G: int):
     b = pl.program_id(0)
     j = pl.program_id(2)
@@ -254,10 +267,11 @@ def _paged_verify_kernel(pos_ref, pt_ref, anc_ref, q_ref, k_ref, v_ref,
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def _paged_verify_kernel_q(pos_ref, pt_ref, anc_ref, q_ref, k_ref, v_ref,
-                           ks_ref, vs_ref, kb_ref, vb_ref, o_ref, m_scr,
-                           l_scr, acc_scr, *, scale: float, tree: bool,
-                           page: int, np_row: int, K: int, G: int):
+def _paged_verify_kernel_q(pos_ref, pt_ref, anc_ref, lay_ref, q_ref, k_ref,
+                           v_ref, ks_ref, vs_ref, kb_ref, vb_ref, o_ref,
+                           m_scr, l_scr, acc_scr, *, scale: float,
+                           tree: bool, page: int, np_row: int, K: int,
+                           G: int):
     """int8-bank verify: cache pages dequantize in VMEM via the
     co-travelling (1, 1, 1, page) scale tiles (folded into the matmuls as
     in ``_paged_decode_kernel_q``); the block's own K keys/values
@@ -321,14 +335,15 @@ def _paged_verify_kernel_q(pos_ref, pt_ref, anc_ref, q_ref, k_ref, v_ref,
 
 
 def paged_verify_attention_kernel(q, k_pages, v_pages, kb, vb, page_table,
-                                  pos, *, scale: float | None = None,
+                                  pos, layer, *, scale: float | None = None,
                                   k_scale=None, v_scale=None, tree=None,
                                   interpret: bool = False) -> jax.Array:
-    """q: (B, Hkv, K*G, hd) — row r is query r//G of kv head h;
-    k_pages/v_pages: (NP, Hkv, page, hd) shared pool BEFORE the block's
-    writes; kb/vb: (B, Hkv, K, hd) block keys/values; page_table: (B, P)
-    int32; pos: (B,) int32 base positions.  ``k_scale``/``v_scale``
-    ((NP, Hkv, 1, page) f32) select the int8 bank path.  ``tree``
+    """q: (B, Hkv, K*G, hd) — row i is query i//G of kv head h;
+    k_pages/v_pages: (R, NP, Hkv, page, hd) stacked pools BEFORE the
+    block's writes; kb/vb: (B, Hkv, K, hd) block keys/values; page_table:
+    (B, P) int32; pos: (B,) int32 base positions; layer: () int32, the
+    pool read.  ``k_scale``/``v_scale`` ((R, NP, Hkv, 1, page) f32)
+    select the int8 bank path.  ``tree``
     ((B, K) int32 ancestor bitmasks) replaces the intra-block causal
     mask with per-row tree visibility (bit j of ``tree[b, i]`` = block
     token j visible to block query i)."""
@@ -336,7 +351,7 @@ def paged_verify_attention_kernel(q, k_pages, v_pages, kb, vb, page_table,
     K = kb.shape[2]
     assert KG % K == 0, (KG, K)
     G = KG // K
-    NP, _, page, _ = k_pages.shape
+    page = k_pages.shape[-2]
     P = page_table.shape[1]
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
@@ -351,13 +366,13 @@ def paged_verify_attention_kernel(q, k_pages, v_pages, kb, vb, page_table,
         is_tree = True
 
     page_spec = pl.BlockSpec(
-        (1, 1, page, hd),
-        lambda b, h, j, pos, pt, anc: (pt[b, j], h, 0, 0))
+        (None, 1, 1, page, hd),
+        lambda b, h, j, pos, pt, anc, lay: (lay[0], pt[b, j], h, 0, 0))
     blk_spec = pl.BlockSpec((1, 1, K, hd),
-                            lambda b, h, j, pos, pt, anc: (b, h, 0, 0))
+                            lambda b, h, j, pos, pt, anc, lay: (b, h, 0, 0))
     in_specs = [
         pl.BlockSpec((1, 1, KG, hd),
-                     lambda b, h, j, pos, pt, anc: (b, h, 0, 0)),
+                     lambda b, h, j, pos, pt, anc, lay: (b, h, 0, 0)),
         page_spec,
         page_spec,
     ]
@@ -367,8 +382,8 @@ def paged_verify_attention_kernel(q, k_pages, v_pages, kb, vb, page_table,
                                    tree=is_tree, page=page, np_row=P,
                                    K=K, G=G)
         scale_spec = pl.BlockSpec(
-            (1, 1, 1, page),
-            lambda b, h, j, pos, pt, anc: (pt[b, j], h, 0, 0))
+            (None, 1, 1, 1, page),
+            lambda b, h, j, pos, pt, anc, lay: (lay[0], pt[b, j], h, 0, 0))
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
     else:
@@ -378,11 +393,12 @@ def paged_verify_attention_kernel(q, k_pages, v_pages, kb, vb, page_table,
     in_specs += [blk_spec, blk_spec]
     operands += [kb, vb]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, Hkv, P),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, KG, hd),
-                               lambda b, h, j, pos, pt, anc: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec(
+            (1, 1, KG, hd),
+            lambda b, h, j, pos, pt, anc, lay: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((KG, 1), jnp.float32),
             pltpu.VMEM((KG, 1), jnp.float32),
@@ -398,12 +414,13 @@ def paged_verify_attention_kernel(q, k_pages, v_pages, kb, vb, page_table,
         interpret=interpret,
         name="paged_verify_attention",
     )(jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,)),
-      jnp.asarray(page_table, jnp.int32), anc, *operands)
+      jnp.asarray(page_table, jnp.int32), anc, _layer_operand(layer),
+      *operands)
 
 
-def _paged_decode_partial_kernel(pos_ref, pt_ref, base_ref, q_ref, k_ref,
-                                 v_ref, acc_ref, m_ref, l_ref, m_scr,
-                                 l_scr, acc_scr, *, scale: float,
+def _paged_decode_partial_kernel(pos_ref, pt_ref, base_ref, lay_ref,
+                                 q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
+                                 m_scr, l_scr, acc_scr, *, scale: float,
                                  page: int, np_row: int, num_local: int):
     b = pl.program_id(0)
     j = pl.program_id(2)
@@ -446,11 +463,11 @@ def _paged_decode_partial_kernel(pos_ref, pt_ref, base_ref, q_ref, k_ref,
         l_ref[0, 0] = l_scr[...]
 
 
-def _paged_decode_partial_kernel_q(pos_ref, pt_ref, base_ref, q_ref,
-                                   k_ref, v_ref, ks_ref, vs_ref, acc_ref,
-                                   m_ref, l_ref, m_scr, l_scr, acc_scr, *,
-                                   scale: float, page: int, np_row: int,
-                                   num_local: int):
+def _paged_decode_partial_kernel_q(pos_ref, pt_ref, base_ref, lay_ref,
+                                   q_ref, k_ref, v_ref, ks_ref, vs_ref,
+                                   acc_ref, m_ref, l_ref, m_scr, l_scr,
+                                   acc_scr, *, scale: float, page: int,
+                                   np_row: int, num_local: int):
     b = pl.program_id(0)
     j = pl.program_id(2)
     pos = pos_ref[b]
@@ -495,13 +512,14 @@ def _paged_decode_partial_kernel_q(pos_ref, pt_ref, base_ref, q_ref,
 
 
 def paged_decode_partial_kernel(q, k_pages, v_pages, page_table, pos,
-                                base, *, scale: float | None = None,
+                                base, layer, *, scale: float | None = None,
                                 k_scale=None, v_scale=None,
                                 interpret: bool = False):
     """Per-shard HALF of flash decode over a sharded page bank.
 
-    ``k_pages``/``v_pages`` here are one shard's (L, Hkv, page, hd)
-    LOCAL slice; ``page_table`` still holds GLOBAL page ids and ``base``
+    ``k_pages``/``v_pages`` here are one shard's (R, L, Hkv, page, hd)
+    LOCAL slice of the stacked pools, of which pool ``layer`` (() int32)
+    is read; ``page_table`` still holds GLOBAL page ids and ``base``
     ((1,) int32, scalar-prefetched) is the shard's first global id, so
     the index map clamps ``pt[b, j] - base`` into [0, L) and the body
     additionally gates each fold on ownership — a foreign page's tile
@@ -513,19 +531,19 @@ def paged_decode_partial_kernel(q, k_pages, v_pages, page_table, pos,
     (0, NEG_INF, 0), which a cross-shard ``exp(m - pmax(m))`` rescale +
     psum combine weighs to exactly zero."""
     B, Hkv, G, hd = q.shape
-    L, _, page, _ = k_pages.shape
+    _, L, _, page, _ = k_pages.shape
     P = page_table.shape[1]
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
     quantized = k_scale is not None
 
-    def _page_idx(b, h, j, pos, pt, base):
-        return (jnp.clip(pt[b, j] - base[0], 0, L - 1), h, 0, 0)
+    def _page_idx(b, h, j, pos, pt, base, lay):
+        return (lay[0], jnp.clip(pt[b, j] - base[0], 0, L - 1), h, 0, 0)
 
-    page_spec = pl.BlockSpec((1, 1, page, hd), _page_idx)
+    page_spec = pl.BlockSpec((None, 1, 1, page, hd), _page_idx)
     in_specs = [
         pl.BlockSpec((1, 1, G, hd),
-                     lambda b, h, j, pos, pt, base: (b, h, 0, 0)),
+                     lambda b, h, j, pos, pt, base, lay: (b, h, 0, 0)),
         page_spec,
         page_spec,
     ]
@@ -534,19 +552,16 @@ def paged_decode_partial_kernel(q, k_pages, v_pages, page_table, pos,
         kernel = functools.partial(_paged_decode_partial_kernel_q,
                                    scale=scale, page=page, np_row=P,
                                    num_local=L)
-        scale_spec = pl.BlockSpec(
-            (1, 1, 1, page),
-            lambda b, h, j, pos, pt, base:
-                (jnp.clip(pt[b, j] - base[0], 0, L - 1), h, 0, 0))
+        scale_spec = pl.BlockSpec((None, 1, 1, 1, page), _page_idx)
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
     else:
         kernel = functools.partial(_paged_decode_partial_kernel,
                                    scale=scale, page=page, np_row=P,
                                    num_local=L)
-    out_idx = lambda b, h, j, pos, pt, base: (b, h, 0, 0)
+    out_idx = lambda b, h, j, pos, pt, base, lay: (b, h, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, Hkv, P),
         in_specs=in_specs,
         out_specs=[
@@ -574,4 +589,5 @@ def paged_decode_partial_kernel(q, k_pages, v_pages, page_table, pos,
         name="paged_decode_partial",
     )(jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,)),
       jnp.asarray(page_table, jnp.int32),
-      jnp.broadcast_to(jnp.asarray(base, jnp.int32), (1,)), *operands)
+      jnp.broadcast_to(jnp.asarray(base, jnp.int32), (1,)),
+      _layer_operand(layer), *operands)

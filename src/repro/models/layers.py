@@ -390,11 +390,19 @@ def commit_page(big: BigKV, act: ActKV, pos) -> BigKV:
 # what keeps a retired slot's stale writes from disturbing pages already
 # recycled to a neighbor (the dual-port disturb-free invariant at page
 # granularity).
+#
+# The model stacks one pool per layer into (R, NP, ...) leaves, and every
+# paged program keeps them stacked: the layer scan carries the whole bank
+# and hands each layer a ``BankLayer`` (the bank plus the layer index).
+# Kernels read the layer through their index maps and every write is
+# ``_bank_write``'s scatter, so the donated bank is updated where it lies
+# and no layer's pool is ever sliced out or copied back.
 # ---------------------------------------------------------------------------
 
 class PagedKV(NamedTuple):
     """Shared page pool: virtual row position j*page+s of a request lives
-    at ``pool[table[j], :, s]`` for that request's page table.
+    at ``pool[table[j], :, s]`` for that request's page table.  The
+    model's bank stacks one pool per layer repeat: leaves (R, NP, ...).
 
     ``ks``/``vs`` are the int8 bank's scale leaves ((NP, Hkv, 1, page)
     f32, ``None`` for full-precision pools): when present, ``k``/``v`` hold
@@ -417,6 +425,17 @@ KV_QMAX = 127.0           # symmetric int8: codes in [-127, 127]
 
 PAGED_LOGICAL = PagedKV(k=("kv_pages", "kv_heads", None, "head_dim"),
                         v=("kv_pages", "kv_heads", None, "head_dim"))
+
+
+class BankLayer(NamedTuple):
+    """Layer ``layer`` (() int32) of a stacked bank (``PagedKV`` with
+    leaves (R, NP, ...)): what one paged attention block reads and
+    writes.  The kernels read the layer through their index maps and
+    writes scatter into it (``_bank_write``), so the bank is not sliced
+    (the jnp reference reads and the global-gather mesh path slice one
+    layer's pool out: ``_layer_pool``)."""
+    bank: PagedKV
+    layer: Any
 
 
 def init_page_pool(cfg: ArchConfig, num_pages: int, page: int,
@@ -466,8 +485,78 @@ from repro.kernels.paged_attention.ref import (  # noqa: E402
     gather_pages as _gather_pages, gather_scales as _gather_scales)
 
 
-def _page_write(cache: PagedKV, k, v, tables, positions, wmask=None):
-    """Scatter (B, K) token k/v into the shared pool.
+def _bank_write(bank: PagedKV, r, pids, new: PagedKV,
+                slots=None) -> PagedKV:
+    """The one write into a stacked bank: ``new``'s leaves go to layer(s)
+    ``r`` and pages ``pids`` (index arrays that broadcast together to an
+    index shape I; a scalar ``r`` is one layer).
+
+    ``slots`` given: token rows — ``new`` holds (I..., Hkv, hd) codes and
+    (I..., Hkv) scales, written at slot ``slots`` of each head.  Page,
+    head and slot are all scatter indices, so the window is ``hd`` alone.
+    A window of (Hkv, hd), with the page axis between them skipped, makes
+    XLA lay the bank out head-major and copy it in and out of that layout
+    around every write.  ``slots`` None: whole pages — (I..., Hkv, page,
+    hd) and a page's Hkv*page scales, a window that is the page itself.
+
+    Either way XLA keeps the bank's own layout and updates the donated
+    buffer in place.  Duplicate indices (parked writes) are allowed."""
+    if slots is None:
+        idx = (r, pids)
+    else:
+        h = jnp.arange(bank.k.shape[2], dtype=jnp.int32)
+        idx = (r, pids[..., None], h, slots[..., None])
+
+    def put(leaf, x):
+        return leaf.at[idx].set(x.astype(leaf.dtype))
+
+    return PagedKV(k=put(bank.k, new.k), v=put(bank.v, new.v),
+                   ks=_write_scales(bank.ks, r, pids, new.ks, slots),
+                   vs=_write_scales(bank.vs, r, pids, new.vs, slots))
+
+
+def _write_scales(leaf, r, pids, x, slots):
+    """``_bank_write`` for a scale leaf (R, NP, Hkv, 1, page), through a
+    (R, NP, Hkv*page/lanes, lanes) view of it.  On the TPU the leaf's
+    (1, page) rows are tiled (1, 128): byte for byte the order of that
+    view tiled (8, 128), so the view is free.  A scatter into the 5-D
+    leaf makes XLA move it to an (8, 128) tiling of (Hkv, page) and back
+    around every write."""
+    if leaf is None:
+        return None
+    Hkv, page = leaf.shape[2], leaf.shape[-1]
+    view = _scale_view(leaf)
+    x = x.astype(leaf.dtype)
+    if slots is None:                       # whole pages
+        lead = jnp.broadcast_shapes(jnp.shape(r), jnp.shape(pids))
+        view = view.at[r, pids].set(x.reshape(lead + view.shape[2:]))
+    else:                                   # one scale per head at a slot
+        lanes = view.shape[-1]
+        off = jnp.arange(Hkv, dtype=jnp.int32) * page + slots[..., None]
+        view = view.at[r, pids[..., None], off // lanes, off % lanes].set(x)
+    return view.reshape(leaf.shape)
+
+
+def _scale_view(leaf):
+    """A scale leaf (R, NP, Hkv, 1, page) as (R, NP, Hkv*page/lanes,
+    lanes): the same bytes on the TPU (see ``_write_scales``)."""
+    R, NP, Hkv, _, page = leaf.shape
+    lanes = math.gcd(page, 128)
+    return leaf.reshape(R, NP, Hkv * page // lanes, lanes)
+
+
+def _stored(bank: PagedKV, k, v) -> PagedKV:
+    """k/v rows (..., hd) as the bank stores them: int8 banks quantize
+    (codes plus one scale per row), full-precision banks keep them."""
+    if bank.ks is None:
+        return PagedKV(k=k, v=v)
+    kq, ksc = quantize_kv(k)
+    vq, vsc = quantize_kv(v)
+    return PagedKV(k=kq, v=vq, ks=ksc, vs=vsc)
+
+
+def _page_write(cache: BankLayer, k, v, tables, positions, wmask=None):
+    """Scatter (B, K) token k/v into layer ``cache.layer`` of the bank.
 
     k/v: (B, K, Hkv, hd); tables: (B, P) int32; positions: (B, K) int32
     virtual positions; ``wmask`` ((B, K) bool, optional) routes False
@@ -475,38 +564,38 @@ def _page_write(cache: PagedKV, k, v, tables, positions, wmask=None):
     non-live rows' per-step decode writes, land in garbage space without
     touching any request's pages.
 
-    int8 pools (``cache.ks is not None``) quantize on write: each token's
+    int8 pools (``ks is not None``) quantize on write: each token's
     (Hkv, hd) k/v rows become int8 codes plus a per-head scale scattered
     into the parallel scale leaf at the same (page, head, slot)."""
+    bank, r = cache
     P = tables.shape[1]
-    page = cache.k.shape[2]
+    page = bank.k.shape[-2]
     positions = jnp.asarray(positions, jnp.int32)
     pidx = jnp.minimum(positions // page, P - 1)    # clamp: parked rows
     pids = jnp.take_along_axis(tables, pidx, axis=1)
     if wmask is not None:
         pids = jnp.where(wmask, pids, PARK_PAGE)
-    slots = positions % page
-    if cache.ks is not None:
-        kq, ksc = quantize_kv(k)                    # (B, K, Hkv, hd/)
-        vq, vsc = quantize_kv(v)
-        return PagedKV(k=cache.k.at[pids, :, slots, :].set(kq),
-                       v=cache.v.at[pids, :, slots, :].set(vq),
-                       ks=cache.ks.at[pids, :, 0, slots].set(ksc),
-                       vs=cache.vs.at[pids, :, 0, slots].set(vsc))
-    k_new = cache.k.at[pids, :, slots, :].set(k.astype(cache.k.dtype))
-    v_new = cache.v.at[pids, :, slots, :].set(v.astype(cache.v.dtype))
-    return PagedKV(k=k_new, v=v_new)
+    return BankLayer(_bank_write(bank, r, pids, _stored(bank, k, v),
+                                 slots=positions % page), r)
 
 
-def _gather_dequant(cache: PagedKV, tables, dtype):
-    """Reference read of an int8 bank: gather codes and scales through
-    the tables, dequantize to ``dtype`` -> (kg, vg) (B, Hkv, P*page, hd).
-    Unwritten positions hold code 0 (dequantizes to exact 0.0 — same
-    masked-garbage story as the full-precision pool)."""
-    kg = dequantize_kv(_gather_pages(cache.k, tables),
-                       _gather_scales(cache.ks, tables), dtype)
-    vg = dequantize_kv(_gather_pages(cache.v, tables),
-                       _gather_scales(cache.vs, tables), dtype)
+def _layer_pool(cache: BankLayer) -> PagedKV:
+    """One layer's pool sliced out of the bank: the jnp reference reads
+    and the global-gather mesh path, which the kernels bypass."""
+    return jax.tree.map(lambda leaf: leaf[cache.layer], cache.bank)
+
+
+def _gather(pool: PagedKV, tables, dtype):
+    """The jnp reference read of one pool: (kg, vg) (B, Hkv, P*page, hd)
+    through the page tables.  An int8 pool's codes and scales gather
+    alike and dequantize to ``dtype``; unwritten positions hold code 0
+    (exact 0.0 — the same masked garbage as the full-precision pool)."""
+    if pool.ks is None:
+        return _gather_pages(pool.k, tables), _gather_pages(pool.v, tables)
+    kg = dequantize_kv(_gather_pages(pool.k, tables),
+                       _gather_scales(pool.ks, tables), dtype)
+    vg = dequantize_kv(_gather_pages(pool.v, tables),
+                       _gather_scales(pool.vs, tables), dtype)
     return kg, vg
 
 
@@ -534,12 +623,13 @@ def _replicated(kernel, shard: Optional[BankShard]):
                      out_specs=Ps(), check_vma=False)
 
 
-def attention_decode_pages(params, x, pos, cache: PagedKV, tables,
+def attention_decode_pages(params, x, pos, cache: BankLayer, tables,
                            cfg: ArchConfig, wmask=None, shard=None):
-    """One-step decode against the shared page pool.  x: (B, 1, D);
-    pos: (B,) int32 (or scalar, broadcast); tables: (B, P) int32;
-    ``wmask`` ((B,) bool, optional): False rows write to the park page
-    (non-live slots must not disturb recycled pages).
+    """One-step decode against layer ``cache.layer`` of the shared page
+    bank.  x: (B, 1, D); pos: (B,) int32 (or scalar, broadcast); tables:
+    (B, P) int32; ``wmask`` ((B,) bool, optional): False rows write to
+    the park page (non-live slots must not disturb recycled pages).
+    Returns (out, the written ``BankLayer``).
 
     Write-then-read in the same order as ``attention_decode`` — the new
     token's k/v land in its page first, then attention reads the gathered
@@ -564,29 +654,37 @@ def attention_decode_pages(params, x, pos, cache: PagedKV, tables,
     if kernels.use_kernels():
         from repro.kernels.paged_attention.ops import paged_decode_attention
         interp = None if kernels.get_mode() == "auto" else True
+        bank, layer = _kernel_bank(cache, shard)
 
-        def kernel(q, k, v, tables, pos, ks, vs):
+        def kernel(q, k, v, tables, pos, ks, vs, layer):
             return paged_decode_attention(q, k, v, tables, pos, k_scale=ks,
-                                          v_scale=vs, interpret=interp)
-        out = _replicated(kernel, shard)(q[:, 0], cache.k, cache.v, tables,
-                                         pos, cache.ks, cache.vs)[:, None]
-    elif cache.ks is not None:
-        kg, vg = _gather_dequant(cache, tables, x.dtype)
-        valid = jnp.arange(kg.shape[2])[None, :] <= pos[:, None]
-        out = decode_sdpa(q, kg, vg, valid, cfg)
+                                          v_scale=vs, layer=layer,
+                                          interpret=interp)
+        out = _replicated(kernel, shard)(q[:, 0], bank.k, bank.v, tables,
+                                         pos, bank.ks, bank.vs,
+                                         layer)[:, None]
     else:
-        kg = _gather_pages(cache.k, tables)
-        vg = _gather_pages(cache.v, tables)
+        kg, vg = _gather(_layer_pool(cache), tables, x.dtype)
         valid = jnp.arange(kg.shape[2])[None, :] <= pos[:, None]
         out = decode_sdpa(q, kg, vg, valid, cfg)
     out = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
     return out, cache
 
 
-def attention_verify_pages(params, x, pos, cache: PagedKV, tables,
+def _kernel_bank(cache: BankLayer, shard):
+    """(bank, layer) for a paged kernel: the whole stacked bank and its
+    layer index; on the global-gather mesh path, one layer's pool (layer
+    None), since every device gathers whole what the kernel reads."""
+    if shard is None:
+        return cache
+    return _layer_pool(cache), None
+
+
+def attention_verify_pages(params, x, pos, cache: BankLayer, tables,
                            cfg: ArchConfig, wmask=None, offsets=None,
                            tree=None, shard=None):
-    """Multi-token verify/chunk decode against the shared page pool.
+    """Multi-token verify/chunk decode against layer ``cache.layer`` of
+    the shared page bank.
 
     x: (B, K, D) block tokens at positions ``pos[b] .. pos[b]+K-1``;
     attention reads the pool as it stood BEFORE the block (through the
@@ -627,20 +725,17 @@ def attention_verify_pages(params, x, pos, cache: PagedKV, tables,
         from repro.kernels.paged_attention.ops import paged_verify_attention
         interp = None if kernels.get_mode() == "auto" else True
 
-        def kernel(q, kp, vp, k, v, tables, pos, ks, vs, tree):
+        bank, layer = _kernel_bank(cache, shard)
+
+        def kernel(q, kp, vp, k, v, tables, pos, ks, vs, tree, layer):
             return paged_verify_attention(q, kp, vp, k, v, tables, pos,
                                           k_scale=ks, v_scale=vs, tree=tree,
-                                          interpret=interp)
-        out = _replicated(kernel, shard)(q, cache.k, cache.v, k, v, tables,
-                                         pos, cache.ks, cache.vs, tree)
-    elif cache.ks is not None:
-        from repro.kernels.verify_attention.ref import verify_reference
-        kg, vg = _gather_dequant(cache, tables, x.dtype)
-        out = verify_reference(q, kg, vg, k, v, pos, ring=False, tree=tree)
+                                          layer=layer, interpret=interp)
+        out = _replicated(kernel, shard)(q, bank.k, bank.v, k, v, tables,
+                                         pos, bank.ks, bank.vs, tree, layer)
     else:
         from repro.kernels.verify_attention.ref import verify_reference
-        kg = _gather_pages(cache.k, tables)
-        vg = _gather_pages(cache.v, tables)
+        kg, vg = _gather(_layer_pool(cache), tables, x.dtype)
         out = verify_reference(q, kg, vg, k, v, pos, ring=False, tree=tree)
 
     cache = _page_write(cache, k, v, tables, positions, wmask=wmask)
@@ -665,6 +760,15 @@ def attention_verify_pages(params, x, pos, cache: PagedKV, tables,
 # allocates any shard's local page 0), so no write crosses shards either —
 # the paper's dual-port disturb-free argument at rack scale.
 # ---------------------------------------------------------------------------
+
+def _bank_leaves(cache: BankLayer):
+    """(leaves, layer) of a bank for a shard_map: the stacked leaves,
+    scale leaves only where the bank is int8, each split on its page
+    axis by ``Ps(None, axis)``."""
+    bank, r = cache
+    leaves = tuple(bank) if bank.ks is not None else (bank.k, bank.v)
+    return leaves, r
+
 
 def _local_pages(tables, num_local: int, axis: str):
     """This shard's view of the (B, P) page table, inside shard_map:
@@ -747,8 +851,9 @@ def _heads_out(out, dt):
                        out.shape[-1]).astype(dt)
 
 
-def attention_decode_pages_sharded(params, x, pos, cache: PagedKV, tables,
-                                   cfg: ArchConfig, shard, wmask=None):
+def attention_decode_pages_sharded(params, x, pos, cache: BankLayer,
+                                   tables, cfg: ArchConfig, shard,
+                                   wmask=None):
     """``attention_decode_pages`` with the bank sharded over mesh axis
     ``shard.axis`` of ``shard.mesh``: each shard writes/reads only its local
     slice (local Pallas partial kernel when kernels are on, jnp partial
@@ -763,20 +868,19 @@ def attention_decode_pages_sharded(params, x, pos, cache: PagedKV, tables,
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     positions = pos[:, None]
     q, k, v = _qkv(params, x, positions, cfg)     # q: (B,1,H,hd)
-    quant = cache.ks is not None
-    bank = ((cache.k, cache.v, cache.ks, cache.vs) if quant
-            else (cache.k, cache.v))
+    bank, r = _bank_leaves(cache)
     tables = jnp.asarray(tables, jnp.int32)
     P = tables.shape[1]
-    page = cache.k.shape[2]
+    page = cache.bank.k.shape[-2]
     scale = 1.0 / (cfg.head_dim ** 0.5)
     dt = x.dtype
     wm = (jnp.ones((B, 1), bool) if wmask is None
           else jnp.asarray(wmask, bool)[:, None])
 
-    def local(bank, q, k, v, tables, pos, wm):
-        lc = PagedKV(*bank)
-        lt, owned = _local_pages(tables, lc.k.shape[0], axis)
+    def local(bank, r, q, k, v, tables, pos, wm):
+        lc = BankLayer(PagedKV(*bank), r)
+        L = lc.bank.k.shape[1]
+        lt, owned = _local_pages(tables, L, axis)
         positions = pos[:, None]
         pidx = jnp.minimum(positions // page, P - 1)
         own_tok = jnp.take_along_axis(owned, pidx, axis=1)   # (B, 1)
@@ -789,40 +893,37 @@ def attention_decode_pages_sharded(params, x, pos, cache: PagedKV, tables,
             from repro.kernels.paged_attention.ops import (
                 paged_decode_partial)
             interp = None if kernels.get_mode() == "auto" else True
-            base = jax.lax.axis_index(axis) * lc.k.shape[0]
+            base = jax.lax.axis_index(axis) * L
+            lb = lc.bank
             acc, m, l = paged_decode_partial(
-                q[:, 0], lc.k, lc.v, tables, pos, base,
-                k_scale=lc.ks, v_scale=lc.vs, interpret=interp)
+                q[:, 0], lb.k, lb.v, tables, pos, base, k_scale=lb.ks,
+                v_scale=lb.vs, layer=r, interpret=interp)
             acc, m, l = acc[:, :, None], m[:, :, None], l[:, :, None]
         else:
-            if lc.ks is not None:
-                kg, vg = _gather_dequant(lc, lt, dt)
-            else:
-                kg = _gather_pages(lc.k, lt)
-                vg = _gather_pages(lc.v, lt)
+            kg, vg = _gather(_layer_pool(lc), lt, dt)
             own_pos = jnp.repeat(owned, page, axis=1)        # (B, S)
             valid = ((jnp.arange(kg.shape[2])[None, :] <= pos[:, None])
                      & own_pos)[:, None, :]                  # (B, 1, S)
             acc, m, l = _paged_partial(q, kg, vg, valid, scale)
         accg, mg, lg = _psum_partials(acc, m, l, axis)
         out = accg / jnp.maximum(lg, 1e-30)[..., None]
-        return out, tuple(lc)[:len(bank)]
+        return out, tuple(lc.bank)[:len(bank)]
 
-    bank_specs = tuple(Ps(axis) for _ in bank)
+    bank_specs = tuple(Ps(None, axis) for _ in bank)
     f = shard_map(local, mesh=mesh,
-                  in_specs=(bank_specs, Ps(), Ps(), Ps(), Ps(), Ps(),
+                  in_specs=(bank_specs, Ps(), Ps(), Ps(), Ps(), Ps(), Ps(),
                             Ps()),
                   out_specs=(Ps(), bank_specs), check_vma=False)
-    out, bank = f(bank, q, k, v, tables, pos, wm)
-    cache = PagedKV(*bank)
+    out, bank = f(bank, r, q, k, v, tables, pos, wm)
+    cache = BankLayer(PagedKV(*bank), r)
     out = _heads_out(out, dt)
     out = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(dt))
     return out, cache
 
 
-def attention_verify_pages_sharded(params, x, pos, cache: PagedKV, tables,
-                                   cfg: ArchConfig, shard, wmask=None,
-                                   offsets=None, tree=None):
+def attention_verify_pages_sharded(params, x, pos, cache: BankLayer,
+                                   tables, cfg: ArchConfig, shard,
+                                   wmask=None, offsets=None, tree=None):
     """``attention_verify_pages`` with per-shard local bank reads (see
     ``attention_decode_pages_sharded``).  The cache side of the
     cache-plus-block split runs as per-shard partials merged with
@@ -839,26 +940,20 @@ def attention_verify_pages_sharded(params, x, pos, cache: PagedKV, tables,
         offsets = jnp.arange(K, dtype=jnp.int32)
     positions = pos[:, None] + jnp.asarray(offsets, jnp.int32)[None]
     q, k, v = _qkv(params, x, positions, cfg)     # q: (B,K,H,hd)
-    quant = cache.ks is not None
-    bank = ((cache.k, cache.v, cache.ks, cache.vs) if quant
-            else (cache.k, cache.v))
+    bank, r = _bank_leaves(cache)
     tables = jnp.asarray(tables, jnp.int32)
     P = tables.shape[1]
-    page = cache.k.shape[2]
+    page = cache.bank.k.shape[-2]
     scale = 1.0 / (cfg.head_dim ** 0.5)
     dt = x.dtype
     wm = (jnp.ones((B, K), bool) if wmask is None
           else jnp.asarray(wmask, bool))
 
-    def local(bank, q, k, v, tables, positions, pos, wm):
-        lc = PagedKV(*bank)
-        lt, owned = _local_pages(tables, lc.k.shape[0], axis)
+    def local(bank, r, q, k, v, tables, positions, pos, wm):
+        lc = BankLayer(PagedKV(*bank), r)
+        lt, owned = _local_pages(tables, lc.bank.k.shape[1], axis)
         # cache side reads the pool as it stood BEFORE the block
-        if lc.ks is not None:
-            kg, vg = _gather_dequant(lc, lt, dt)
-        else:
-            kg = _gather_pages(lc.k, lt)
-            vg = _gather_pages(lc.v, lt)
+        kg, vg = _gather(_layer_pool(lc), lt, dt)
         own_pos = jnp.repeat(owned, page, axis=1)
         valid = ((jnp.arange(kg.shape[2])[None, :] < pos[:, None])
                  & own_pos)[:, None, :]                      # (B, 1, S)
@@ -867,16 +962,16 @@ def attention_verify_pages_sharded(params, x, pos, cache: PagedKV, tables,
         pidx = jnp.minimum(positions // page, P - 1)
         own_tok = jnp.take_along_axis(owned, pidx, axis=1)   # (B, K)
         lc = _page_write(lc, k, v, lt, positions, wmask=own_tok & wm)
-        return parts, tuple(lc)[:len(bank)]
+        return parts, tuple(lc.bank)[:len(bank)]
 
-    bank_specs = tuple(Ps(axis) for _ in bank)
+    bank_specs = tuple(Ps(None, axis) for _ in bank)
     f = shard_map(local, mesh=mesh,
-                  in_specs=(bank_specs, Ps(), Ps(), Ps(), Ps(), Ps(),
+                  in_specs=(bank_specs, Ps(), Ps(), Ps(), Ps(), Ps(), Ps(),
                             Ps(), Ps()),
                   out_specs=((Ps(), Ps(), Ps()), bank_specs),
                   check_vma=False)
-    (accg, mg, lg), bank = f(bank, q, k, v, tables, positions, pos, wm)
-    cache = PagedKV(*bank)
+    (accg, mg, lg), bank = f(bank, r, q, k, v, tables, positions, pos, wm)
+    cache = BankLayer(PagedKV(*bank), r)
     Hkv = cfg.num_kv_heads
     hd = cfg.head_dim
     qh = (q.reshape(B, K, Hkv, -1, hd).transpose(0, 2, 1, 3, 4)
@@ -888,39 +983,32 @@ def attention_verify_pages_sharded(params, x, pos, cache: PagedKV, tables,
 
 
 def insert_pages(cache: PagedKV, rows: KVCache, tables) -> PagedKV:
-    """Admission: scatter freshly prefilled cache rows (B, Hkv, S, hd)
-    into the shared pool through (B, P) page tables (S == P*page).  Dead
-    table entries (past a row's allocation) point at the park page, so
-    the unconditional all-P scatter parks the rows' zero tails instead of
-    touching anyone's pages.  Only the named pages change — the same
-    disturb-free contract as ``LM.insert_cache_rows``."""
-    B, Hkv, S, hd = rows.k.shape
+    """Admission: scatter freshly prefilled cache rows (R, B, Hkv, S, hd)
+    into the stacked bank (R, NP, ...) through (B, P) page tables
+    (S == P*page), every layer at once.  Dead table entries (past a
+    row's allocation) point at the park page, so the unconditional all-P
+    scatter parks the rows' zero tails instead of touching anyone's
+    pages.  Only the named pages change — the same disturb-free contract
+    as ``LM.insert_cache_rows``."""
+    R, B, Hkv, S, hd = rows.k.shape
     P = tables.shape[1]
-    page = cache.k.shape[2]
+    page = cache.k.shape[-2]
     assert S == P * page, (S, P, page)
 
-    def paged_view(r):
-        return (r.reshape(B, Hkv, P, page, hd)
-                .transpose(0, 2, 1, 3, 4))          # (B, P, Hkv, page, hd)
+    def paged_view(x):                      # (R, B, P, Hkv, page, hd)
+        return (x.reshape(R, B, Hkv, P, page, hd)
+                .transpose(0, 1, 3, 2, 4, 5))
 
-    if cache.ks is not None:                        # quantize on insert
-        kq, ksc = quantize_kv(paged_view(rows.k))
-        vq, vsc = quantize_kv(paged_view(rows.v))
-        return PagedKV(k=cache.k.at[tables].set(kq),
-                       v=cache.v.at[tables].set(vq),
-                       ks=cache.ks.at[tables].set(ksc[..., None, :]),
-                       vs=cache.vs.at[tables].set(vsc[..., None, :]))
-
-    def scatter(pool, r):
-        return pool.at[tables].set(paged_view(r).astype(pool.dtype))
-
-    return PagedKV(k=scatter(cache.k, rows.k), v=scatter(cache.v, rows.v))
+    new = _stored(cache, paged_view(rows.k), paged_view(rows.v))
+    layer = jnp.arange(R, dtype=jnp.int32)[:, None, None]
+    return _bank_write(cache, layer, tables[None], new)
 
 
 def copy_pages(cache: PagedKV, src, dst) -> PagedKV:
-    """Device-side page copy: ``pool[dst[i]] = pool[src[i]]`` for every
-    leaf of the bank (codes AND scales for an int8 pool — the copy is a
-    byte copy, never a re-quantization).  src/dst: (n,) int32 page ids.
+    """Device-side page copy: ``bank[:, dst[i]] = bank[:, src[i]]`` in
+    every layer and leaf of the stacked bank (codes AND scales for an
+    int8 pool — the copy is a byte copy, never a re-quantization).
+    src/dst: (n,) int32 page ids.
 
     This is the copy-on-write primitive of prefix sharing: a request
     that diverges mid-page gets a private copy of the shared boundary
@@ -929,12 +1017,12 @@ def copy_pages(cache: PagedKV, src, dst) -> PagedKV:
     would have produced."""
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
-
-    def cp(pool):
-        return None if pool is None else pool.at[dst].set(pool[src])
-
-    return PagedKV(k=cp(cache.k), v=cp(cache.v),
-                   ks=cp(cache.ks), vs=cp(cache.vs))
+    new = PagedKV(k=cache.k[:, src], v=cache.v[:, src])
+    if cache.ks is not None:                # read through the write's view
+        new = new._replace(ks=_scale_view(cache.ks)[:, src],
+                           vs=_scale_view(cache.vs)[:, src])
+    layer = jnp.arange(cache.k.shape[0], dtype=jnp.int32)[:, None]
+    return _bank_write(cache, layer, dst[None], new)
 
 
 def attention_decode(params, x, pos, cache: KVCache, cfg: ArchConfig):

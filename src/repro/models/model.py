@@ -186,11 +186,12 @@ class LM:
 
         ``tables`` ((B, P) int32, decode/verify modes) switches the
         attention cache to the shared page pool: ``cache`` is then a
-        ``layers.PagedKV`` bank addressed through the per-row page
-        tables, and ``wmask`` gates writes for decode too (non-live rows
-        park).  ``offsets``/``tree`` (paged verify only) select tree
-        verification — per-node depth offsets and per-row ancestor
-        bitmasks; see ``layers.attention_verify_pages``.  ``shard``
+        ``layers.BankLayer`` (this layer of the stacked bank) addressed
+        through the per-row page tables, and ``wmask`` gates writes for
+        decode too (non-live rows park).  ``offsets``/``tree`` (paged
+        verify only) select tree verification — per-node depth offsets
+        and per-row ancestor bitmasks; see
+        ``layers.attention_verify_pages``.  ``shard``
         (a ``layers.BankShard``, paged modes only) says the page bank is
         split over a mesh: with ``local_read`` the paged attention is
         shard_mapped so each mesh shard reads only its local slice (see
@@ -278,6 +279,10 @@ class LM:
                     wmask=None, tables=None, offsets=None, tree=None,
                     shard=None):
         """Scan over repeats; python-unrolled period inside the body."""
+        if tables is not None:
+            return self._run_paged_blocks(params, x, mode, pos, caches,
+                                          wmask, tables, offsets, tree,
+                                          shard)
         pattern = self.pattern
 
         def body(carry, xs):
@@ -313,6 +318,38 @@ class LM:
                 body, (x, jnp.zeros((), jnp.float32)),
                 (params["blocks"], caches), unroll=unroll)
         return x, aux, ys
+
+    def _run_paged_blocks(self, params, x, mode, pos, banks, wmask, tables,
+                          offsets, tree, shard):
+        """``_run_blocks`` over the stacked page banks (leaves (R, NP,
+        ...)).  The banks ride in the scan carry with a layer counter and
+        each block reads and writes its layer in place
+        (``layers.BankLayer``); the scan's xs are the params alone.  As xs
+        and ys (the row caches' path) every layer would slice its pool
+        out of the bank and stack it into a fresh output bank, copied
+        back into the donated buffer at the end; see
+        ``decode_step_paged``."""
+        pattern = self.pattern
+
+        def body(carry, params_r):
+            x, aux, r, banks = carry
+            banks = dict(banks)
+            for i, typ in enumerate(pattern):
+                key = f"b{i}"
+                x, view, a = self._apply_block(
+                    typ, params_r[key], x, None, mode, pos,
+                    layers.BankLayer(banks[key], r), wmask=wmask,
+                    tables=tables, offsets=offsets, tree=tree, shard=shard)
+                banks[key] = view.bank
+                aux = aux + a
+            return (x, aux, r + 1, banks), None
+
+        unroll = self.repeats if self.scan_unroll else 1
+        (x, aux, _, banks), _ = jax.lax.scan(
+            body, (x, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32),
+                   banks),
+            params["blocks"], unroll=unroll)
+        return x, aux, banks
 
     # ---------------------------------------------------------------- modes
     def forward(self, params, tokens, patch_embeds=None, remat: bool = False):
@@ -500,8 +537,8 @@ class LM:
         Only the named pages (plus the park page) change — the paged
         analogue of ``insert_cache_rows``."""
         tables = jnp.asarray(tables, jnp.int32)
-        ins = jax.vmap(layers.insert_pages, in_axes=(0, 0, None))
-        return {key: ins(c, rows[key], tables) for key, c in caches.items()}
+        return {key: layers.insert_pages(c, rows[key], tables)
+                for key, c in caches.items()}
 
     def copy_cache_pages(self, caches, src, dst):
         """Copy-on-write support: duplicate pool pages ``src[i]`` into
@@ -510,10 +547,8 @@ class LM:
         page table is layer-shared, so one (src, dst) pair names the same
         position range in every bank; everything outside ``dst`` is
         untouched."""
-        src = jnp.asarray(src, jnp.int32)
-        dst = jnp.asarray(dst, jnp.int32)
-        cp = jax.vmap(layers.copy_pages, in_axes=(0, None, None))
-        return {key: cp(c, src, dst) for key, c in caches.items()}
+        return {key: layers.copy_pages(c, src, dst)
+                for key, c in caches.items()}
 
     def decode_step_pages(self, params, caches, tokens, pos, tables,
                           live=None, shard=None):

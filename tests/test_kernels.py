@@ -245,6 +245,59 @@ def test_paged_verify_attention_matches_row_oracle(B, H, Hkv, S, hd, page,
                                atol=_tol(dtype), rtol=1e-2)
 
 
+def _stacked_bank(R, NP, Hkv, page, hd, quantized, seed):
+    """Random stacked pools (R, NP, Hkv, page, hd): bf16 values, or int8
+    codes with (R, NP, Hkv, 1, page) f32 scales."""
+    ks = jax.random.split(jax.random.key(seed), 4)
+    shape = (R, NP, Hkv, page, hd)
+    if not quantized:
+        return (jax.random.normal(ks[0], shape, jnp.bfloat16),
+                jax.random.normal(ks[1], shape, jnp.bfloat16), None, None)
+    code = lambda k: jax.random.randint(k, shape, -127, 128,  # noqa: E731
+                                        jnp.int32).astype(jnp.int8)
+    scale = lambda k: jax.random.uniform(  # noqa: E731
+        k, (R, NP, Hkv, 1, page), jnp.float32, 1e-3, 2e-2)
+    return code(ks[0]), code(ks[1]), scale(ks[2]), scale(ks[3])
+
+
+@pytest.mark.parametrize("layer", ["first", "last"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("kind", ["decode", "verify", "tree", "partial"])
+def test_stacked_bank_kernel_matches_one_pool(kind, quantized, layer):
+    """A paged kernel handed the whole stacked bank and a layer index
+    gives bitwise what it gives on that layer's pool alone."""
+    from repro.kernels.paged_attention.ops import (
+        paged_decode_attention, paged_decode_partial, paged_verify_attention)
+    R, NP, B, H, Hkv, page, hd, P, K = 3, 9, 2, 4, 2, 16, 32, 4, 3
+    r = 0 if layer == "first" else R - 1
+    kp, vp, kss, vss = _stacked_bank(R, NP, Hkv, page, hd, quantized, R + K)
+    keys = jax.random.split(jax.random.key(7), 3)
+    table = jax.random.randint(keys[0], (B, P), 1, NP, jnp.int32)
+    pos = jnp.asarray([page * P - 1, page + 3], jnp.int32)
+    one = lambda x: None if x is None else x[r]  # noqa: E731
+
+    if kind in ("decode", "partial"):
+        q = jax.random.normal(keys[1], (B, H, hd), jnp.bfloat16)
+        if kind == "decode":
+            f = lambda k, v, ks, vs, **kw: paged_decode_attention(  # noqa
+                q, k, v, table, pos, k_scale=ks, v_scale=vs, **kw)
+        else:                    # a shard whose slice starts at page 4
+            f = lambda k, v, ks, vs, **kw: paged_decode_partial(  # noqa
+                q, k, v, table, pos, 4, k_scale=ks, v_scale=vs, **kw)
+    else:
+        q = jax.random.normal(keys[1], (B, K, H, hd), jnp.bfloat16)
+        blk = jax.random.normal(keys[2], (2, B, K, Hkv, hd), jnp.bfloat16)
+        tree = (jnp.asarray([[1, 3, 5], [1, 3, 7]], jnp.int32)
+                if kind == "tree" else None)
+        f = lambda k, v, ks, vs, **kw: paged_verify_attention(  # noqa
+            q, k, v, blk[0], blk[1], table, pos, k_scale=ks, v_scale=vs,
+            tree=tree, **kw)
+    got = f(kp, vp, kss, vss, layer=jnp.int32(r), interpret=True)
+    want = f(one(kp), one(vp), one(kss), one(vss), interpret=True)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 # ---------------------------------------------------------------------------
 # selective scan
 # ---------------------------------------------------------------------------

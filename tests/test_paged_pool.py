@@ -339,6 +339,94 @@ def test_chunk_scatter_is_page_granular(f32_lm):
 
 
 # ---------------------------------------------------------------------------
+# disturb-free writes through the carried bank
+# ---------------------------------------------------------------------------
+
+def _random_bank(m, NP, page, quantized, seed=3):
+    """A page bank full of random values, so that an untouched entry is
+    told apart from a rewritten one."""
+    bank = m.init_page_pool(NP, page, quantized=quantized)
+    leaves, tree = jax.tree.flatten(bank)
+    keys = jax.random.split(jax.random.key(seed), len(leaves))
+    leaves = [jax.random.randint(k, x.shape, -127, 128).astype(x.dtype)
+              if x.dtype == jnp.int8 else
+              jax.random.uniform(k, x.shape, x.dtype, 0.5, 2.0)
+              for k, x in zip(keys, leaves)]
+    return jax.tree.unflatten(tree, leaves)
+
+
+def _assert_only(old, new, written):
+    """Every bank leaf changed exactly at the ``written`` (layer, page,
+    head, slot) entries: bitwise the same everywhere else, rewritten
+    there."""
+    for o, n in zip(jax.tree.leaves(old), jax.tree.leaves(new)):
+        o, n = np.asarray(o), np.asarray(n)
+        if o.shape[3] == 1:                      # int8 scales: (.., 1, page)
+            o, n = o[:, :, :, 0, :, None], n[:, :, :, 0, :, None]
+        same = (o == n).all(axis=-1)             # (R, NP, Hkv, page)
+        assert same[~written].all()
+        assert not same[written].any()
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_carried_bank_writes_only_its_entries(f32_lm, program, quantized):
+    """A decode step and a prefill chunk through the carried bank change
+    only the (layer, page, head, slot) entries they write, in every
+    layer; the park page changes only at parked writes (a non-live
+    decode row, a chunk's pad tokens)."""
+    cfg, m, p = f32_lm
+    page, P = 8, 4
+    NP = 3 * P + 1
+    bank = _random_bank(m, NP, page, quantized)
+    tables = np.arange(1, NP).reshape(3, P)[:, ::-1].copy()
+    R = m.repeats
+    written = np.zeros((R, NP, cfg.num_kv_heads, page), bool)
+    if program == "decode":
+        pos = np.array([5, 17, 30])
+        live = np.array([True, False, True])
+        _, new = m.decode_step_pages(
+            p, bank, jnp.asarray([[3], [4], [5]], jnp.int32),
+            jnp.asarray(pos, jnp.int32), jnp.asarray(tables, jnp.int32),
+            live=jnp.asarray(live))
+        for b in range(3):
+            pid = tables[b, pos[b] // page] if live[b] else 0
+            written[:, pid, :, pos[b] % page] = True
+    else:
+        C, pos, nvalid = 12, 6, 9
+        _, new = m.prefill_chunk_pages(
+            p, bank, tokens_for(cfg, batch=1, seq=C),
+            jnp.asarray([pos], jnp.int32),
+            jnp.asarray(tables[1:2], jnp.int32),
+            wmask=jnp.arange(C)[None, :] < nvalid, need_logits=False)
+        for i in range(C):
+            pid = tables[1, (pos + i) // page] if i < nvalid else 0
+            written[:, pid, :, (pos + i) % page] = True
+    for key in bank:
+        _assert_only(bank[key], new[key], written)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+def test_page_write_touches_one_layer(quantized):
+    """The write of one block lands in its own layer of the stacked bank:
+    every other layer's pages stay bitwise untouched."""
+    from repro.models import layers
+    cfg = reduced_arch("tinyllama-1.1b", num_layers=3)
+    m = build_model(cfg, cache_dtype=jnp.float32)
+    page, Hkv, hd = 8, cfg.num_kv_heads, cfg.head_dim
+    bank = _random_bank(m, 9, page, quantized)["b0"]
+    k, v = jax.random.normal(jax.random.key(1), (2, 2, 3, Hkv, hd))
+    tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
+    positions = jnp.asarray([[9, 10, 11], [0, 1, 2]], jnp.int32)
+    view = layers._page_write(layers.BankLayer(bank, jnp.int32(1)), k, v,
+                              tables, positions)
+    written = np.zeros((3, 9, Hkv, page), bool)
+    written[1, 2, :, 1:4] = True                 # row 0: page 2, slots 1-3
+    written[1, 5, :, 0:3] = True                 # row 1: page 5, slots 0-2
+    _assert_only(bank, view.bank, written)
+
+
+# ---------------------------------------------------------------------------
 # admission priority: short prompts jump queued chunk work, fairly
 # ---------------------------------------------------------------------------
 
