@@ -88,7 +88,7 @@ def analyse(cell: str) -> dict:
     from repro.models.model import build_model
     bench = Benchmark()
     c = bench.cell(cell)
-    cfg = load_config(bench.config(c.config), c.config)
+    cfg = load_config(bench, c.config)
     tspec = bench.traffic(c.traffic)
     page, chunk = SCHED["page_size"], SCHED["prefill_chunk"]
     max_len = traffic_mod.max_len(tspec, page)

@@ -21,6 +21,7 @@ import threading
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 from typing import Optional
 
 import numpy as np
@@ -30,9 +31,8 @@ from chipbench.spec import ROOT, Benchmark
 from chipbench.tails import percentile
 
 SCHED = dict(paged=True, page_size=256, prefill_chunk=256, multi_step=4)
-# trace names: kernel op names, and the jitted programs of each phase
-KERNELS = {"paged_decode": "paged_decode_attention",
-           "paged_verify": "paged_verify_attention"}
+# trace names of the jitted programs of each phase (a family names its
+# kernels in its own ``KERNELS``)
 PROGRAMS = {"decode": {"_step", "_mstep"},
             "prefill": {"_chunk", "_chunk_final"}}
 # the raw observations the per-layer readers use (scheduler clock)
@@ -46,12 +46,14 @@ class NoChip(RuntimeError):
 
 @dataclass
 class Config:
-    """A configuration file, resolved against the program's arch."""
+    """A configuration file, resolved by its family against the
+    program's arch."""
     name: str
     raw: dict
-    dims: costs.Dims
+    dims: object                   # the family's dims
     models: list
-    arch: object = None            # repro ArchConfig as served
+    arch: object                   # repro ArchConfig as served
+    family: ModuleType             # families/<raw["family"]>.py
 
     @property
     def serving(self) -> dict:
@@ -65,26 +67,12 @@ class Config:
         return int(max(1, min(s["max_batch"], b)))
 
 
-def load_config(raw: dict, name: str) -> Config:
-    from repro.configs import get_arch, override
-    d = costs.Dims.of(raw)
-    base = get_arch(raw["arch"])
-    widths = {"d_model": d.d_model, "num_heads": d.heads,
-              "num_kv_heads": d.kv_heads, "head_dim": d.head_dim,
-              "d_ff": d.d_ff, "vocab_size": d.vocab}
-    bad = {k: (getattr(base, k), v) for k, v in widths.items()
-           if getattr(base, k) != v}
-    if bad:
-        raise ValueError(f"{name}: widths differ from the program's "
-                         f"{raw['arch']!r} (program, file): {bad}")
-    if raw.get("tie_word_embeddings") or raw.get("attention_bias"):
-        raise ValueError(f"{name}: the served layer has no biases and an "
-                         "untied head")
-    arch = override(base, num_layers=d.layers, norm_eps=raw["rms_norm_eps"],
-                    rope_theta=float(raw["rope_theta"]),
-                    param_dtype=raw["torch_dtype"], dtype="bfloat16",
-                    **widths)
-    return Config(name, raw, d, list(raw["models"]), arch)
+def load_config(bench: Benchmark, name: str) -> Config:
+    """Configuration ``name``, resolved by the family its file names."""
+    raw = bench.config(name)
+    family = bench.family(raw["family"])
+    dims, arch = family.resolve(raw, name)
+    return Config(name, raw, dims, list(raw["models"]), arch, family)
 
 
 class Recorder:
@@ -117,7 +105,7 @@ class Run:
     reqs: list
     raw: dict
     ctx: dict                       # context-engine counter deltas
-    dims: costs.Dims
+    dims: object                    # the family's dims
     peak: dict
     work: costs.Work
     trace: Optional[object] = None  # trace_reduce.Summary
@@ -220,7 +208,8 @@ def build(cfg: Config, seed: int, max_len: int):
     hosts = {}
     for i, name in enumerate(cfg.models):
         model = build_model(cfg.arch, cache_dtype=jnp.bfloat16)
-        dev = weights_mod.draw(cfg.dims, weights_mod.key_for(seed, i))
+        dev = weights_mod.draw(cfg.family.layout(cfg.dims),
+                               weights_mod.key_for(seed, i))
         weights_mod.check_layout(dev, model.abstract())
         host = jax.device_get(dev)
         del dev
@@ -354,8 +343,7 @@ def served_gaps(picked: list, hosts: dict, cfg: Config,
         if not mine:
             continue
         g = reference.served_gaps(
-            weights_mod.flatten(hosts[name]), cfg.dims,
-            float(cfg.raw["rms_norm_eps"]), float(cfg.raw["rope_theta"]),
+            weights_mod.flatten(hosts[name]), cfg.family, cfg.dims, cfg.raw,
             [r.tokens for r in mine], [r.output for r in mine],
             control=control)
         out["served"] += g["served"]
@@ -397,7 +385,7 @@ def measure(cell: str, seed: int, seconds: float, trace: bool,
     if require_chip:
         enable_cache()
     compiles = _Compiles.listening()
-    cfg = load_config(bench.config(c.config), c.config)
+    cfg = load_config(bench, c.config)
     tspec = bench.traffic(c.traffic)
     limits = bench.limits(cell)
     page = SCHED["page_size"]
@@ -431,8 +419,8 @@ def measure(cell: str, seed: int, seconds: float, trace: bool,
     work = costs.Work()
     for r in reqs:
         if r.output is not None:
-            costs.request_work(cfg.dims, len(r.tokens), len(r.output),
-                               SCHED["prefill_chunk"], peak, work)
+            cfg.family.request_work(cfg.dims, len(r.tokens), len(r.output),
+                                    SCHED["prefill_chunk"], peak, work)
     run = Run(cell=cell, seconds=seconds, t0=t0, t_close=t0 + seconds,
               reqs=reqs, raw=registry.raw, ctx=ctx,
               dims=cfg.dims, peak=peak, work=work, compiles=compiles.n,
@@ -452,7 +440,8 @@ def measure(cell: str, seed: int, seconds: float, trace: bool,
     if trace:
         from chipbench import trace_reduce
         run.trace = trace_reduce.summarize(
-            trace_reduce.xplane_file(trace_dir), KERNELS, PROGRAMS)
+            trace_reduce.xplane_file(trace_dir), cfg.family.KERNELS,
+            PROGRAMS)
         dev["busy_s"] = run.trace.busy_s
         dev["window_s"] = run.trace.window_s
         shutil.rmtree(trace_dir, ignore_errors=True)

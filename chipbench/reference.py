@@ -1,10 +1,10 @@
 """Plain reference of the served model, and the lower-precision control.
 
-The published DeepSeek LLM 7B layer (arXiv:2401.02954; the Llama layer of
-its ``config.json``): RMSNorm, rotate-half RoPE, causal multi-head
-attention scaled by ``1/sqrt(head_dim)``, SwiGLU MLP, no biases, untied
-head.  Written from those equations in ``jax.numpy`` alone: no import of
-the program, no cache, no kernels, no batching of requests.
+The numerics every family's reference shares (``_mm``, ``_rms``,
+``_rope``, the head and the gap); a family's ``hidden`` in
+``families/<family>.py`` writes its layers from the published equations
+with them, in ``jax.numpy`` alone: no import of the program, no cache, no
+kernels, no batching of requests.
 
 It reads the benchmark's own weights (``weights.py``) and runs in
 float32 at ``Precision.HIGHEST``, one layer at a time over every sequence
@@ -22,8 +22,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from chipbench.costs import Dims
 
 HI = jax.lax.Precision.HIGHEST
 BLOCK = 512                     # sequences pad to a multiple of this
@@ -58,33 +56,6 @@ def _rope(x, theta):
     return x * cos + jnp.concatenate([-x2, x1], -1) * sin
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "theta", "fp8", "qb"))
-def layer(x, w, *, eps, theta, fp8, qb=BLOCK):
-    """One decoder layer over one sequence; x: (T, D) float32."""
-    w = {k: v.astype(jnp.float32) for k, v in w.items()}
-    T = x.shape[0]
-    h = _rms(x, w["norm1"], eps)
-    q = _rope(_mm("td,dhk->thk", h, w["wq"], fp8), theta)
-    k = _rope(_mm("td,dhk->thk", h, w["wk"], fp8), theta)
-    v = _mm("td,dhk->thk", h, w["wv"], fp8)
-    rep = q.shape[1] // k.shape[1]
-    k, v = jnp.repeat(k, rep, 1), jnp.repeat(v, rep, 1)
-    scale = 1.0 / np.sqrt(q.shape[-1])
-    outs = []
-    for s in range(0, T, qb):               # query blocks bound the scores
-        qs = q[s:s + qb]
-        sc = jnp.einsum("qhk,thk->hqt", qs, k, precision=HI) * scale
-        mask = (s + jnp.arange(qs.shape[0]))[:, None] >= jnp.arange(T)
-        p = jax.nn.softmax(jnp.where(mask, sc, -jnp.inf), axis=-1)
-        outs.append(jnp.einsum("hqt,thk->qhk", p, v, precision=HI))
-    a = jnp.concatenate(outs, 0)
-    x = x + _mm("thk,hkd->td", a, w["wo"], fp8)
-    h = _rms(x, w["norm2"], eps)
-    g = _mm("td,df->tf", h, w["w_gate"], fp8)
-    u = _mm("td,df->tf", h, w["w_up"], fp8)
-    return x + _mm("tf,fd->td", jax.nn.silu(g) * u, w["w_down"], fp8)
-
-
 ROWS = 256                      # compared positions pad to this many
 
 
@@ -102,33 +73,6 @@ def _gap(lg, toks):
     return jnp.max(lg, -1) - jnp.take_along_axis(lg, toks[:, None], -1)[:, 0]
 
 
-_LAYER_KEYS = {"norm1": "blocks/b0/norm1", "norm2": "blocks/b0/norm2",
-               "wq": "blocks/b0/attn/wq", "wk": "blocks/b0/attn/wk",
-               "wv": "blocks/b0/attn/wv", "wo": "blocks/b0/attn/wo",
-               "w_gate": "blocks/b0/mlp/w_gate",
-               "w_up": "blocks/b0/mlp/w_up",
-               "w_down": "blocks/b0/mlp/w_down"}
-
-
-def hidden(flat: dict, d: Dims, eps: float, theta: float,
-           seqs: list[np.ndarray], fp8: bool = False) -> list:
-    """Last-layer hidden states of each token sequence, on the device.
-    ``flat`` maps leaf paths to host arrays (``weights.flatten``)."""
-    emb = np.asarray(flat["embed"])
-    xs = []
-    for toks in seqs:
-        T = -(-len(toks) // BLOCK) * BLOCK
-        x = np.zeros((T, d.d_model), np.float32)
-        x[:len(toks)] = emb[toks].astype(np.float32)
-        xs.append(jax.device_put(x))
-    for li in range(d.layers):
-        w = {k: jax.device_put(np.asarray(flat[p][li]))
-             for k, p in _LAYER_KEYS.items()}
-        xs = [layer(x, w, eps=eps, theta=theta, fp8=fp8) for x in xs]
-        del w
-    return xs
-
-
 def _pad(a: np.ndarray) -> np.ndarray:
     if len(a) > ROWS:
         raise ValueError(f"{len(a)} compared positions; at most {ROWS}")
@@ -136,7 +80,7 @@ def _pad(a: np.ndarray) -> np.ndarray:
         np.int32)
 
 
-def served_gaps(flat: dict, d: Dims, eps: float, theta: float,
+def served_gaps(flat: dict, family, d, raw: dict,
                 prompts: list[np.ndarray], outputs: list[np.ndarray],
                 control: bool = False) -> dict:
     """For each request, the gap by which each served token's reference
@@ -146,7 +90,9 @@ def served_gaps(flat: dict, d: Dims, eps: float, theta: float,
     positions ``len(prompt) - 1 + j`` predict served token j.  With
     ``control`` the same is read for the token the float8 control puts
     first at each position (teacher-forced on the same tokens), under
-    key ``"control"``."""
+    key ``"control"``.  ``family`` is the configuration's family module,
+    ``d`` its dims and ``raw`` the configuration file."""
+    eps = float(raw["rms_norm_eps"])
     seqs = [np.concatenate([p, o[:-1]]).astype(np.int32)
             for p, o in zip(prompts, outputs)]
     rows = [_pad(np.arange(len(p) - 1, len(p) - 1 + len(o)))
@@ -156,11 +102,11 @@ def served_gaps(flat: dict, d: Dims, eps: float, theta: float,
     w = jax.device_put(flat["lm_head"])
     top = []
     if control:
-        xs = hidden(flat, d, eps, theta, seqs, fp8=True)
+        xs = family.hidden(flat, d, raw, seqs, fp8=True)
         top = [jnp.argmax(head(x, r, norm, w, eps=eps, fp8=True), -1)
                .astype(jnp.int32) for x, r in zip(xs, rows)]
         del xs
-    xs = hidden(flat, d, eps, theta, seqs)
+    xs = family.hidden(flat, d, raw, seqs)
     out = {"served": [], "control": []}
     for i, (x, r) in enumerate(zip(xs, rows)):
         lg = head(x, r, norm, w, eps=eps, fp8=False)

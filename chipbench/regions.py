@@ -251,7 +251,7 @@ def profile(cell: str, seed: int, seconds: float, bench=None,
     if require_chip:
         harness.enable_cache()
     compiles = harness._Compiles.listening()
-    cfg = harness.load_config(bench.config(c.config), c.config)
+    cfg = harness.load_config(bench, c.config)
     tspec = bench.traffic(c.traffic)
     max_len = traffic_mod.max_len(tspec, harness.SCHED["page_size"])
     server, registry, _ = harness.build(cfg, seed, max_len)
@@ -282,7 +282,7 @@ def profile(cell: str, seed: int, seconds: float, bench=None,
         sched.stop(drain=False)
         server.shutdown()
     xplane = trace_reduce.xplane_file(trace_dir)
-    summary = trace_reduce.summarize(xplane, harness.KERNELS,
+    summary = trace_reduce.summarize(xplane, cfg.family.KERNELS,
                                      harness.PROGRAMS)
     regions = summarize(xplane)
     shutil.rmtree(trace_dir, ignore_errors=True)

@@ -7,19 +7,29 @@ piece lives in a file of its own, found by name:
     chipbench/traffic/<traffic>.json  parameters of the one traffic generator
     chipbench/cells/<workload>.json   the correctness limit and its readings
     chipbench/metrics/<metric>.py     one reader per per-layer metric
+    chipbench/families/<family>.py    a configuration's layers: widths,
+                                      weights, reference and costs
 
-so a cell, a mix or a metric is added by adding files, with no edit here.
+so a cell, a mix, a metric or a family of layers is added by adding
+files, with no edit here.
 """
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import json
+import sys
 from dataclasses import dataclass
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Optional
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
+
+# One module per file and process: a family's jitted reference layer is
+# built, and compiled, once however many configurations or runs use it.
+_LOADED: dict[Path, ModuleType] = {}
 
 
 @dataclass(frozen=True)
@@ -93,14 +103,26 @@ class Benchmark:
     def limits(self, cell: str) -> dict:
         return self._json("cells", cell)
 
+    def _module(self, sub: str, name: str, what: str) -> ModuleType:
+        path = (self.dir / sub / f"{name}.py").resolve()
+        if not path.is_file():
+            raise FileNotFoundError(f"no {what} {name!r}: {path}")
+        if path not in _LOADED:
+            tag = hashlib.sha1(str(path).encode()).hexdigest()[:12]
+            spec = importlib.util.spec_from_file_location(
+                f"chipbench_{sub}_{name.replace('.', '_')}_{tag}", path)
+            mod = importlib.util.module_from_spec(spec)
+            sys.modules[spec.name] = mod  # dataclasses look their module up
+            spec.loader.exec_module(mod)
+            _LOADED[path] = mod
+        return _LOADED[path]
+
     def reader(self, metric: str) -> Callable:
         """The ``read(run)`` function of ``metrics/<metric>.py``."""
-        path = self.dir / "metrics" / f"{metric}.py"
-        if not path.is_file():
-            raise FileNotFoundError(f"no reader for metric {metric!r}: "
-                                    f"{path}")
-        spec = importlib.util.spec_from_file_location(
-            "chipbench_metric_" + metric.replace(".", "_"), path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return self._module("metrics", metric, "reader for metric").read
+
+    def family(self, name: str) -> ModuleType:
+        """The module ``families/<name>.py``: ``resolve``, ``layout``,
+        ``hidden``, ``KERNELS`` and ``request_work`` of one family of
+        layers (see ``families/dense.py``)."""
+        return self._module("families", name, "family of layers")

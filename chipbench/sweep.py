@@ -64,7 +64,7 @@ def sweep(cell: str, seconds: float, rates: list, seed: int = 1) -> list:
     c = bench.cell(cell)
     harness.device_info(c.chips, True)
     harness.enable_cache()
-    cfg = harness.load_config(bench.config(c.config), c.config)
+    cfg = harness.load_config(bench, c.config)
     tspec = bench.traffic(c.traffic)
     max_len = traffic_mod.max_len(tspec, harness.SCHED["page_size"])
     batch = cfg.batch(max_len)

@@ -2,46 +2,22 @@
 
 The benchmark makes the weights itself, so the reference can read the very
 same arrays without taking anything the program made.  The tree follows
-the layout the serving program takes for a dense all-attention model
-(stacked layers under ``blocks/b0``); ``check_layout`` holds it against the
-program's own abstract parameters, so a layout change fails loudly.
+the layout the serving program takes for the configuration's family
+(``layout`` of ``families/<family>.py``); ``check_layout`` holds it
+against the program's own abstract parameters, so a layout change fails
+loudly.
 
-Scales follow fan-in (``1/sqrt(fan_in)`` for projections, 0.02 for the
-embedding and the head), which keeps activations and logits at the sizes
-of a trained model; norm weights are drawn around 1 so that a norm that
-drops its weight does not go unnoticed.
+A family's scales follow fan-in (``1/sqrt(fan_in)`` for projections, 0.02
+for the embedding and the head), which keeps activations and logits at
+the sizes of a trained model; norm weights are drawn around 1 (``NORM``)
+so that a norm that drops its weight does not go unnoticed.
 """
 from __future__ import annotations
-
-import math
 
 import jax
 import jax.numpy as jnp
 
-from chipbench.costs import Dims
-
 NORM, NORMAL = "norm", "normal"
-
-
-def layout(d: Dims) -> dict:
-    """``{path: (shape, kind, std)}`` of every leaf."""
-    L, D, H, K, hd, F, V = (d.layers, d.d_model, d.heads, d.kv_heads,
-                            d.head_dim, d.d_ff, d.vocab)
-    s_in, s_o, s_f = 1 / math.sqrt(D), 1 / math.sqrt(H * hd), 1 / math.sqrt(F)
-    return {
-        "embed": ((V, D), NORMAL, 0.02),
-        "final_norm": ((D,), NORM, 0.1),
-        "lm_head": ((D, V), NORMAL, 0.02),
-        "blocks/b0/norm1": ((L, D), NORM, 0.1),
-        "blocks/b0/norm2": ((L, D), NORM, 0.1),
-        "blocks/b0/attn/wq": ((L, D, H, hd), NORMAL, s_in),
-        "blocks/b0/attn/wk": ((L, D, K, hd), NORMAL, s_in),
-        "blocks/b0/attn/wv": ((L, D, K, hd), NORMAL, s_in),
-        "blocks/b0/attn/wo": ((L, H, hd, D), NORMAL, s_o),
-        "blocks/b0/mlp/w_gate": ((L, D, F), NORMAL, s_in),
-        "blocks/b0/mlp/w_up": ((L, D, F), NORMAL, s_in),
-        "blocks/b0/mlp/w_down": ((L, F, D), NORMAL, s_f),
-    }
 
 
 def _nest(flat: dict) -> dict:
@@ -69,9 +45,10 @@ def key_for(seed: int, model: int):
     return jax.random.fold_in(jax.random.key(seed), model)
 
 
-def draw(d: Dims, key, dtype=jnp.bfloat16) -> dict:
-    """All weights in one jitted call on the default device."""
-    lay = layout(d)
+def draw(lay: dict, key, dtype=jnp.bfloat16) -> dict:
+    """All weights of ``lay`` (``{path: (shape, kind, std)}``, a family's
+    ``layout``) in one jitted call on the default device: one key per
+    leaf, split from ``key`` in the order of the sorted paths."""
 
     def gen(key):
         keys = jax.random.split(key, len(lay))

@@ -1,6 +1,7 @@
 """Every piece of a cell is found by its name: a configuration, a mix, a
-cell's limits or a metric reader added as a file is used with no edit of
-the harness.  The committed ``BENCHMARK.json`` keeps to its own shape."""
+cell's limits, a metric reader or a family of layers added as a file is
+used with no edit of the harness.  The committed ``BENCHMARK.json``
+keeps to its own shape."""
 from chipbench_testkit import tiny_bench  # noqa: F401
 import json
 import re
@@ -22,11 +23,23 @@ def test_added_files_are_found_by_name(tiny_bench):
         json.dumps({"logit_gap_max": 0.5}))
     (d / "metrics" / "new.metric_ms.py").write_text(
         "def read(run):\n    return 42.0 if run else None\n")
+    (d / "families" / "new_family.py").write_text("KERNELS = {'k': 'op'}\n")
     assert bench.config("other") == {"x": 1}
     assert bench.traffic("burst2") == {"y": 2}
     assert bench.limits("new.cell")["logit_gap_max"] == 0.5
     read = bench.reader("new.metric_ms")
     assert read(object()) == 42.0 and read(None) is None
+    assert bench.family("new_family").KERNELS == {"k": "op"}
+
+
+def test_a_file_is_loaded_once_per_process(tiny_bench):
+    one, other = tiny_bench(), tiny_bench(family="moe_probe")
+    dense = one.family("dense")
+    assert Benchmark(one.path, one.dir).family("dense") is dense
+    assert one.reader("mfu.decode") is one.reader("mfu.decode")
+    # the same name in another tree is another module
+    assert other.family("dense") is not dense
+    assert other.family("dense").__name__ != dense.__name__
 
 
 def test_a_missing_piece_names_what_is_missing(tiny_bench):
@@ -35,6 +48,8 @@ def test_a_missing_piece_names_what_is_missing(tiny_bench):
         bench.traffic("nope")
     with pytest.raises(FileNotFoundError, match="no reader"):
         bench.reader("nope")
+    with pytest.raises(FileNotFoundError, match="no family"):
+        bench.family("nope")
     with pytest.raises(KeyError, match="unknown workload"):
         bench.cell("nope")
 
@@ -59,6 +74,7 @@ def test_committed_benchmark_is_complete_and_well_formed():
     for c in raw["configs"]:
         assert NAME.match(c["name"]) and (ROOT / c["file"]).is_file()
         assert bench.config(c["name"])["reduced"] == c["reduced"]
+        bench.family(bench.config(c["name"])["family"])
     used = {w["config"] for w in raw["workloads"]}
     assert used == set(names)
     pairs = [(w["config"], w["traffic"]) for w in raw["workloads"]]
@@ -88,10 +104,12 @@ def test_committed_benchmark_is_complete_and_well_formed():
 
 def test_a_configuration_off_the_published_widths_is_refused():
     from chipbench import harness
-    raw = Benchmark().config("deepseek-7b.l15")
-    harness.load_config(raw, "deepseek-7b.l15")
+    bench = Benchmark()
+    raw = bench.config("deepseek-7b.l15")
+    cfg = harness.load_config(bench, "deepseek-7b.l15")
+    assert cfg.dims.layers == cfg.arch.num_layers == 15
     with pytest.raises(ValueError, match="widths differ"):
-        harness.load_config(dict(raw, intermediate_size=4096), "narrow")
+        cfg.family.resolve(dict(raw, intermediate_size=4096), "narrow")
 
 
 def test_warm_up_leaves_the_most_popular_models_resident_last():

@@ -1,17 +1,19 @@
-"""The plain reference follows the published layer: at toy widths and in
-float32 it gives the serving program's own full forward pass, and its
-float8 control lands far from it."""
-import chipbench_testkit  # noqa: F401
+"""The dense family's plain reference follows the published layer: at toy
+widths and in float32 it gives the serving program's own full forward
+pass, and its float8 control lands far from it."""
+from chipbench_testkit import family
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import costs, reference, weights
+from chipbench import reference, weights
 
-D = costs.Dims(layers=2, d_model=64, heads=4, kv_heads=4, head_dim=16,
+dense = family("dense")
+D = dense.Dims(layers=2, d_model=64, heads=4, kv_heads=4, head_dim=16,
                d_ff=128, vocab=512)
 EPS, THETA = 1e-6, 10000.0
+RAW = {"rms_norm_eps": EPS, "rope_theta": THETA}
 
 
 def _program_logits(tree, tokens):
@@ -37,7 +39,7 @@ def _program_logits(tree, tokens):
 
 
 def _reference_logits(flat, tokens, fp8=False):
-    x = reference.hidden(flat, D, EPS, THETA, [tokens], fp8=fp8)[0]
+    x = dense.hidden(flat, D, RAW, [tokens], fp8=fp8)[0]
     rows = reference._pad(np.arange(len(tokens)))
     lg = reference.head(x, jnp.asarray(rows),
                         jnp.asarray(flat["final_norm"]),
@@ -47,7 +49,8 @@ def _reference_logits(flat, tokens, fp8=False):
 
 @pytest.fixture(scope="module")
 def tree():
-    return weights.draw(D, weights.key_for(2 ** 31 + 5, 0), jnp.float32)
+    return weights.draw(dense.layout(D), weights.key_for(2 ** 31 + 5, 0),
+                        jnp.float32)
 
 
 def test_reference_matches_the_program_forward(tree):
@@ -66,7 +69,7 @@ def test_served_gaps_are_zero_for_the_reference_own_tokens(tree):
         seq.append(int(_reference_logits(flat, np.array(seq))[-1]
                        .argmax()))
     out = np.array(seq[len(prompt):])
-    g = reference.served_gaps(flat, D, EPS, THETA, [prompt], [out],
+    g = reference.served_gaps(flat, dense, D, RAW, [prompt], [out],
                               control=True)
     assert g["served"][0].shape == (6,)
     assert np.abs(g["served"][0]).max() <= 1e-5

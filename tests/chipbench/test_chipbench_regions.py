@@ -3,14 +3,13 @@ synthetic spans, on a recorded trace of a program without regions, and
 on a whole CPU run of a toy cell with the program's tracer on, where the
 benchmark's time pairing of first tokens is held against each request's
 own first-token stamp."""
-import chipbench_testkit  # noqa: F401
-from chipbench_testkit import tiny_bench  # noqa: F401
+from chipbench_testkit import family, tiny_bench  # noqa: F401
 from pathlib import Path
 
 import pytest
 
 from chipbench import regions, trace_reduce
-from chipbench.harness import KERNELS, PROGRAMS
+from chipbench.harness import PROGRAMS
 
 DATA = Path(__file__).resolve().parent / "data"
 SEED = 2 ** 31 + 91
@@ -84,7 +83,7 @@ def test_prog_spans_clip_to_the_window_and_load_host_share():
 def test_trace_of_a_program_without_regions_reads_nothing():
     path = DATA / "tiny_v5e.xplane.pb.gz"
     r = regions.summarize(path)
-    s = trace_reduce.summarize(path, KERNELS, PROGRAMS)
+    s = trace_reduce.summarize(path, family("dense").KERNELS, PROGRAMS)
     assert r.idle_by_region == {} and r.prog_spans == {}
     assert r.window_s == pytest.approx(s.window_s)
     assert r.idle_s == pytest.approx(s.window_s - s.busy_s, rel=1e-9)

@@ -1,12 +1,12 @@
 """Trace reduction: interval arithmetic by hand, and the whole reduction
 on a small trace recorded on a TPU v5e (toy widths, 2 s window)."""
-import chipbench_testkit  # noqa: F401
+from chipbench_testkit import family
 from pathlib import Path
 
 import pytest
 
 from chipbench import trace_reduce
-from chipbench.harness import KERNELS, PROGRAMS
+from chipbench.harness import PROGRAMS
 
 DATA = Path(__file__).resolve().parent / "data"
 
@@ -42,8 +42,8 @@ def test_op_names():
 def recorded():
     """``tiny_v5e.xplane.pb.gz``: the toy-width tiny cell (2 layers,
     d_model 64) traced for a 2 s window on one TPU v5e."""
-    return trace_reduce.summarize(DATA / "tiny_v5e.xplane.pb.gz", KERNELS,
-                                  PROGRAMS)
+    return trace_reduce.summarize(DATA / "tiny_v5e.xplane.pb.gz",
+                                  family("dense").KERNELS, PROGRAMS)
 
 
 def test_recorded_trace_window_and_busy(recorded):
